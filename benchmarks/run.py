@@ -33,6 +33,7 @@ from benchmarks.common import (
     telemetry_snapshot,
     write_suite_json,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.perfgate.references import RefSpec
 
 
@@ -171,6 +172,7 @@ def main() -> None:
                     help="small suite sizes (CI / CPU smoke)")
     ap.add_argument("--out", default="results/bench.csv")
     args = ap.parse_args()
+    use_compile_cache()
 
     keys = args.only.split(",") if args.only else list(SUITES)
     unknown = [k for k in keys if k not in SUITES]

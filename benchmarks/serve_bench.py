@@ -31,18 +31,24 @@ from benchmarks.common import Report
 from repro import obs
 from repro.core.api import plan_cache_info, topological_signature
 from repro.core.persistence_jax import diagrams_bitwise_equal
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import TopoServe, TopoServeConfig
 from repro.serve.topo_serve import pack_requests
 
 
-def _query_stream(n_queries: int, seed: int = 0):
-    """Synthetic ego-net-regime queries spanning the bucket ladder."""
+def _query_stream(n_queries: int, seed: int = 0,
+                  n_range: tuple[int, int] = (6, 56)):
+    """Synthetic ego-net-regime queries spanning the bucket ladder.
+
+    Orders are drawn uniformly from ``n_range`` (half-open); the default
+    spans the three smaller buckets.
+    """
     import networkx as nx
 
     rng = np.random.default_rng(seed)
     out = []
     for _ in range(n_queries):
-        n = int(rng.integers(6, 56))
+        n = int(rng.integers(*n_range))
         kind = rng.integers(0, 3)
         if kind == 0:
             g = nx.gnp_random_graph(n, float(rng.uniform(0.1, 0.3)),
@@ -267,6 +273,7 @@ def main() -> None:
                     help="detune the watched drain to force one SLO "
                          "breach + flight dump (CI smoke)")
     args = ap.parse_args()
+    use_compile_cache()
     report = Report()
     run(report, quick=args.quick, inject_slow_drain=args.inject_slow_drain)
     print(report.csv())

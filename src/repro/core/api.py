@@ -313,7 +313,6 @@ def _build_executor(key: TopoPlanKey) -> Callable[[GraphBatch], Diagrams]:
     # and inserts 0.6-3 GB/device batch all-gathers on a 256-chip mesh,
     # §Perf iteration 5).  The global batch must divide the mesh size; the
     # serve layer pads bucket batches to guarantee this.
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = key.mesh
@@ -322,11 +321,11 @@ def _build_executor(key: TopoPlanKey) -> Callable[[GraphBatch], Diagrams]:
     def per_device(adj, mask, f):
         return _pipeline(GraphBatch(adj=adj, mask=mask, f=f), key)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=Diagrams(birth=spec, death=spec, dim=spec, valid=spec),
-        check_rep=False,
+        check_vma=False,
     )
 
     def executor(g: GraphBatch) -> Diagrams:

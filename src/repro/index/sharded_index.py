@@ -43,7 +43,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -57,6 +56,7 @@ from repro.index.topo_index import (
 )
 from repro.kernels import tuning
 from repro.kernels.hamming import hamming_scan_pallas, pack_codes_u32
+from repro.kernels.ops import _interpret
 from repro.kernels.pairwise_gram import pairwise_l1_pallas
 from repro.launch.mesh import make_index_mesh
 from repro.launch.sharding import index_gram_specs, index_row_spec
@@ -70,10 +70,6 @@ _C_SCANS = obs.counter(
     help="ShardedIndex device-side coarse scans / SUMMA gram calls")
 _C_ROWS = obs.counter(
     "index.sharded_rows", help="corpus rows scanned across all shards")
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 class ShardedIndex:
@@ -232,11 +228,11 @@ class ShardedIndex:
                 neg, loc = jax.lax.top_k(-dist, m_loc)
                 return (-neg)[None], (shard * per + loc)[None]
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(index_row_spec(), P(None, None), P(None, None)),
                 out_specs=(P(("row", "col"), None, None),) * 2,
-                check_rep=False,
+                check_vma=False,
             )(codes_all, q_codes, q_mask)
 
         def summa(q_blocks, corpus):
@@ -271,11 +267,11 @@ class ShardedIndex:
                 _, out = jax.lax.fori_loop(0, rows_ax, step, (qb, out0))
                 return out.reshape(rows_ax * qb_rows, db.shape[0])
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(query_spec, corpus_spec),
                 out_specs=out_spec,
-                check_rep=False,
+                check_vma=False,
             )(q_blocks, corpus)
 
         self._scan_fn = jax.jit(scan, static_argnames=("m_loc",))

@@ -65,6 +65,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -77,6 +78,44 @@ DEFAULT_REV_EVERY = 8
 # collapsed-assignment code for "person matched to the collapsed diagonal
 # reservoir" (the OUT pseudo-object); -1 keeps meaning "free"
 OUT = -2
+
+
+def eps_ladder_values(eps0: float, eps_factor: float,
+                      n_scales: int) -> list[float]:
+    """The ε ladder ``eps0·factor^-s`` as f32-exact Python floats.
+
+    Computed once on the host, so every program (the Pallas kernel, the
+    jnp oracle, either one interpreted or compiled) bakes in the same
+    literals: an in-program ``pow`` is folded by XLA in one context and
+    evaluated at run time in another, which can move ε — and with it every
+    price — by an ulp.
+    """
+    f = np.float32
+    return [float(f(eps0) * f(eps_factor) ** f(-s)) for s in range(n_scales)]
+
+
+def _ladder(scale_idx, values):
+    """Per-scale ε over ``scale_idx`` from literal ``values`` (selects,
+    not a captured array: a Pallas kernel may close over scalars only)."""
+    out = jnp.full(scale_idx.shape, values[0], jnp.float32)
+    for s, v in enumerate(values[1:], 1):
+        out = jnp.where(scale_idx == s, v, out)
+    return out
+
+
+def _sum_in_order(terms):
+    """Σ over the last axis, left to right, kept as a ``(..., 1)`` column.
+
+    Each term is extracted exactly (a one-hot sum) and added in index
+    order: a tree reduction's association depends on the program it is
+    fused into, and the kernel and the oracle must agree to the bit.
+    """
+    lane = lax.broadcasted_iota(jnp.int32, terms.shape, terms.ndim - 1)
+    total = jnp.zeros(terms.shape[:-1] + (1,), terms.dtype)
+    for i in range(terms.shape[-1]):
+        total = total + jnp.sum(jnp.where(lane == i, terms, 0.0), axis=-1,
+                                keepdims=True)
+    return total
 
 
 def default_max_rounds(m: int) -> int:
@@ -146,7 +185,8 @@ def auction_solve(cost, eps0: float = DEFAULT_EPS0,
     c_scale = jnp.maximum(jnp.max(jnp.abs(cost)), 1e-30)
     a = -(cost / c_scale)
     idx = jnp.arange(m)
-    eps_ladder = eps0 * eps_factor ** -jnp.arange(n_scales, dtype=jnp.float32)
+    eps_ladder = _ladder(jnp.arange(n_scales),
+                         eps_ladder_values(eps0, eps_factor, n_scales))
 
     def run_scale(carry, eps):
         price, p2o, o2p, rounds = carry
@@ -201,7 +241,8 @@ def auction_solve(cost, eps0: float = DEFAULT_EPS0,
              & (rank_p[:, None] == rank_o[None, :]))
     fill = jnp.max(jnp.where(match, idx[None, :], -1), axis=-1)
     assign = jnp.where(free_p, fill, p2o)
-    total = jnp.sum(jnp.take_along_axis(cost, assign[:, None], axis=-1))
+    total = _sum_in_order(
+        jnp.take_along_axis(cost, assign[:, None], axis=-1)[:, 0])[0]
     return assign, total, converged, rounds
 
 
@@ -209,43 +250,90 @@ def auction_solve(cost, eps0: float = DEFAULT_EPS0,
 # collapsed (reservoir-free) forward/reverse auction
 # --------------------------------------------------------------------------
 
+def _pick(x, j, axis):
+    """``x``'s entry at index ``j`` along ``axis`` (``j`` has x's shape
+    without that axis).  A one-hot max, bit-identical to
+    ``take_along_axis`` on floats: Mosaic lowers a gather only in its 2-D
+    same-shape form, so the collapsed solver never emits one.
+    """
+    pos = lax.broadcasted_iota(jnp.int32, x.shape, axis % x.ndim)
+    hit = pos == jnp.expand_dims(j, axis)
+    return jnp.max(jnp.where(hit, x, -jnp.inf), axis=axis)
+
+
+def _any(x):
+    """Per-pair ``any`` over the last axis, kept as a ``(..., 1)`` column."""
+    return jnp.any(x, axis=-1, keepdims=True)
+
+
+def _all(x):
+    """Per-pair ``all`` over the last axis, kept as a ``(..., 1)`` column."""
+    return jnp.all(x, axis=-1, keepdims=True)
+
+
+def _col(x):
+    """(..., K) -> (..., K, 1); bools go through int32, since Mosaic has
+    no lane-to-sublane reshape of an i1 vector."""
+    if x.dtype == jnp.bool_:
+        return x.astype(jnp.int32)[..., :, None] != 0
+    return x[..., :, None]
+
+
+def _row(x):
+    """(..., K) -> (..., 1, K), with bools through int32 as in :func:`_col`."""
+    if x.dtype == jnp.bool_:
+        return x.astype(jnp.int32)[..., None, :] != 0
+    return x[..., None, :]
+
+
+def _grids(k):
+    """(K, K) person (row) and object (column) index planes."""
+    return (lax.broadcasted_iota(jnp.int32, (k, k), 0),
+            lax.broadcasted_iota(jnp.int32, (k, k), 1))
+
+
+# The collapsed rounds and solver take a leading pair axis on every operand
+# — ``a`` (B, K, K), vectors (B, K), per-pair scalars (B, 1) — and never a
+# 1-D vector, a gather, a batched ``cond`` or a scan with per-step outputs:
+# the forms Mosaic refuses.  One batched definition serves the Pallas
+# kernel (B = tile_b) and, through ``auction_solve_collapsed``, the jnp
+# oracle, so the two stay bit-identical.
+
 def collapsed_bid_round(a, price, pi, p2o, o2p, eps):
     """One synchronous *forward* round of the collapsed auction.
 
-    ``a``: (K, K) benefit = −reduced-cost, ``-inf`` at invalid pairs;
-    ``price``: (K,) real-object prices; ``pi``: (K,) person profits;
-    ``p2o`` ∈ {OUT, −1=free, j}; ``o2p`` ∈ {−1=unowned, i}.  Every free
-    person's option set is its real objects *plus* OUT (value 0, price
-    pinned at 0, unlimited capacity): persons whose best real value is
-    ≤ 0 take OUT immediately — the collapsed reservoir absorbs any number
-    of takers in one round, which is exactly the tie blowup the expanded
-    matrix pays ~M rounds for — and the rest bid best-over-second-best + ε
-    with OUT folded into the second-best.
+    ``a``: (B, K, K) benefit = −reduced-cost, ``-inf`` at invalid pairs;
+    ``price``: (B, K) real-object prices; ``pi``: (B, K) person profits;
+    ``p2o`` ∈ {OUT, −1=free, j}; ``o2p`` ∈ {−1=unowned, i}; ``eps`` (B, 1).
+    Every free person's option set is its real objects *plus* OUT (value
+    0, price pinned at 0, unlimited capacity): persons whose best real
+    value is ≤ 0 take OUT immediately — the collapsed reservoir absorbs
+    any number of takers in one round, which is exactly the tie blowup the
+    expanded matrix pays ~M rounds for — and the rest bid
+    best-over-second-best + ε with OUT folded into the second-best.
     """
-    k = a.shape[-1]
-    idx = jnp.arange(k)
+    row, col = _grids(a.shape[-1])
     free = p2o == -1
-    v = a - price[None, :]
+    v = a - price[..., None, :]
     j_star = jnp.argmax(v, axis=-1)
     v1 = jnp.max(v, axis=-1)
-    v2 = jnp.max(jnp.where(idx[None, :] == j_star[:, None], -jnp.inf, v),
+    v2 = jnp.max(jnp.where(col == j_star[..., :, None], -jnp.inf, v),
                  axis=-1)
     v2o = jnp.maximum(v2, 0.0)         # second-best option including OUT
     take_out = free & (v1 <= 0.0)      # OUT is (weakly) the best option
     bid_ok = free & (v1 > 0.0)
-    aj = jnp.take_along_axis(a, j_star[:, None], axis=-1)[:, 0]
-    bid = aj - v2o + eps
-    bids = jnp.where(bid_ok[:, None] & (j_star[:, None] == idx[None, :]),
-                     bid[:, None], -jnp.inf)          # (person, object)
-    best = jnp.max(bids, axis=0)
-    winner = jnp.argmax(bids, axis=0)
+    bid = _pick(a, j_star, -1) - v2o + eps
+    bids = jnp.where(_col(bid_ok) & (j_star[..., :, None] == col),
+                     bid[..., :, None], -jnp.inf)     # (person, object)
+    best = jnp.max(bids, axis=-2)
+    winner = jnp.argmax(bids, axis=-2)
     has = best > -jnp.inf
     price = jnp.where(has, best, price)
-    lost = jnp.any(has[None, :] & (o2p[None, :] == idx[:, None]), axis=-1)
+    lost = jnp.any(_row(has) & (o2p[..., None, :] == row), axis=-1)
     p2o = jnp.where(lost, -1, p2o)
     o2p = jnp.where(has, winner, o2p)
-    won = jnp.max(jnp.where(has[None, :] & (winner[None, :] == idx[:, None]),
-                            idx[None, :], -1), axis=-1)
+    won = jnp.max(jnp.where(_row(has) & (winner[..., None, :] == row),
+                            col, -1), axis=-1)
     p2o = jnp.where(won >= 0, won, p2o)
     # winners' profits: value of the second-best option they forwent, −ε —
     # the ε-CS-consistent dual update the reverse rounds price against
@@ -267,37 +355,164 @@ def collapsed_reverse_round(a, price, pi, p2o, o2p, keep2, eps):
     profit.  A person receiving several offers accepts the best one
     (Jacobi conflict resolution — losers keep their old price and retry),
     and the accepted person's previous object is released with its price
-    intact, to be repaired by a later reverse round.
+    intact, to be repaired by a later reverse round.  Shapes as in
+    :func:`collapsed_bid_round`.
     """
-    k = a.shape[-1]
-    idx = jnp.arange(k)
+    row, col = _grids(a.shape[-1])
     bidder = keep2 & (o2p < 0) & (price > 0.0)
-    w = a - pi[:, None]                # (person, object)
-    i_star = jnp.argmax(w, axis=0)
-    b1 = jnp.max(w, axis=0)
-    b2 = jnp.max(jnp.where(idx[:, None] == i_star[None, :], -jnp.inf, w),
-                 axis=0)
+    w = a - pi[..., :, None]           # (person, object)
+    i_star = jnp.argmax(w, axis=-2)
+    b1 = jnp.max(w, axis=-2)
+    b2 = jnp.max(jnp.where(row == i_star[..., None, :], -jnp.inf, w),
+                 axis=-2)
     drop = bidder & (b1 < eps)
     active = bidder & (b1 >= eps)
     p_new = jnp.maximum(0.0, b2 - eps)
-    offer = jnp.take_along_axis(a, i_star[None, :], axis=0)[0, :] - p_new
-    offers = jnp.where(active[None, :] & (i_star[None, :] == idx[:, None]),
-                       offer[None, :], -jnp.inf)      # (person, object)
-    best_off = jnp.max(offers, axis=1)
-    j_win = jnp.argmax(offers, axis=1)
+    offer = _pick(a, i_star, -2) - p_new
+    offers = jnp.where(_row(active) & (i_star[..., None, :] == row),
+                       offer[..., None, :], -jnp.inf)  # (person, object)
+    best_off = jnp.max(offers, axis=-1)
+    j_win = jnp.argmax(offers, axis=-1)
     got = best_off > -jnp.inf
     # accepted persons release their old object (an owned object is never
     # a bidder, so freed/taken are disjoint and update order is immaterial)
-    freed = jnp.any(got[:, None] & (p2o[:, None] == idx[None, :]), axis=0)
-    won_obj = got[:, None] & (j_win[:, None] == idx[None, :])
-    taken = jnp.any(won_obj, axis=0)
-    new_owner = jnp.max(jnp.where(won_obj, idx[:, None], -1), axis=0)
+    freed = jnp.any(_col(got) & (p2o[..., :, None] == col), axis=-2)
+    won_obj = _col(got) & (j_win[..., :, None] == col)
+    taken = jnp.any(won_obj, axis=-2)
+    new_owner = jnp.max(jnp.where(won_obj, row, -1), axis=-2)
     o2p = jnp.where(freed, -1, o2p)
     o2p = jnp.where(taken, new_owner, o2p)
     price = jnp.where(taken, p_new, jnp.where(drop, 0.0, price))
     p2o = jnp.where(got, j_win, p2o)
     pi = jnp.where(got, best_off, pi)
     return price, pi, p2o, o2p
+
+
+def solve_collapsed_batch(cbar, keep1, keep2, price0,
+                          eps0: float = DEFAULT_EPS0,
+                          eps_factor: float = DEFAULT_EPS_FACTOR,
+                          n_scales: int = DEFAULT_N_SCALES,
+                          max_rounds: int | None = None,
+                          rev_every: int = DEFAULT_REV_EVERY):
+    """:func:`auction_solve_collapsed` over a leading pair axis.
+
+    ``cbar`` (B, K, K), ``keep1``/``keep2``/``price0`` (B, K).  Returns
+    ``(p2o (B, K), total (B, 1), converged (B, 1), rounds (B, 1),
+    price (B, K))``.  Each pair's bidding loop runs until that pair stops,
+    exactly as ``vmap`` of the one-pair solver would: the loop runs while
+    any pair is live and a finished pair's state is held.
+    """
+    k = cbar.shape[-1]
+    if max_rounds is None:
+        max_rounds = default_max_rounds(k)
+    rev_every = int(rev_every)
+    cbar = cbar.astype(jnp.float32)
+    valid = _col(keep1) & _row(keep2)
+    c_scale = jnp.maximum(jnp.max(jnp.max(
+        jnp.where(valid, jnp.abs(cbar), 0.0), axis=-1), axis=-1,
+        keepdims=True), 1e-30)[..., None]
+    a = jnp.where(valid, -(cbar / c_scale), -jnp.inf)
+    scale_idx = lax.broadcasted_iota(jnp.int32, (1, n_scales), 1)
+    ladder = eps_ladder_values(eps0, eps_factor, n_scales)
+    eps_ladder = _ladder(scale_idx, ladder)
+    price = jnp.where(keep2, jnp.maximum(price0.astype(jnp.float32), 0.0),
+                      0.0)
+    # warm start (any nonzero price) skips the annealing ladder: coarse
+    # scales would inflate the already-equilibrated prices and then pay
+    # reverse rounds to re-ground them, so a warm lane runs every scale
+    # iteration at the finest ε instead (auction from arbitrary nonneg
+    # prices + empty assignment preserves ε-CS, so the ε_final optimality
+    # certificate is unchanged; the ladder is purely a cold-start speedup)
+    eps_ladder = jnp.where(_any(price > 0.0), ladder[-1], eps_ladder)
+    # initial profits must over-claim nothing: best attainable value now
+    pi = jnp.maximum(jnp.max(a - price[..., None, :], axis=-1), 0.0)
+    # invalid persons sit at OUT for good (cbar row is -inf, never bid)
+    p2o = jnp.where(keep1, -1, OUT).astype(jnp.int32)
+    o2p = jnp.full(p2o.shape, -1, jnp.int32)
+    row, col = _grids(k)
+    # per-pair counters and flags are int32 columns: Mosaic cannot carry
+    # an i1 vector through a loop
+    zero = jnp.zeros(p2o.shape[:-1] + (1,), jnp.int32)
+
+    def stale(price, o2p):
+        return _any(keep2 & (o2p < 0) & (price > 0.0))
+
+    def run_scale(sc, carry):
+        price, pi, p2o, o2p, rounds, sel, any_conv, late_conv = carry
+        eps = jnp.max(jnp.where(scale_idx == sc, eps_ladder, -jnp.inf),
+                      axis=-1, keepdims=True)
+        # ε-CS partial reset: persons keep their slot (real object or OUT)
+        # only while it is still within eps of their best option at the
+        # new, finer scale; freed persons re-bid, and the objects they
+        # abandon keep their stale prices for the reverse rounds to repair
+        v = a - price[..., None, :]
+        best = jnp.maximum(jnp.max(v, axis=-1), 0.0)
+        mine = jnp.where(p2o >= 0, _pick(v, jnp.clip(p2o, 0), -1),
+                         0.0)                        # OUT is worth exactly 0
+        keep = (p2o != -1) & (mine >= best - eps)
+        keep = keep | ~keep1
+        p2o = jnp.where(keep, p2o, -1)
+        o2p = jnp.max(jnp.where(p2o[..., :, None] == col, row, -1), axis=-2)
+
+        def live(s):
+            price, pi, p2o, o2p, prev, it, stalled = s
+            return ((_any(p2o == -1) | stale(price, o2p))
+                    & (it < max_rounds) & (stalled == 0))
+
+        def body(s):
+            price, pi, p2o, o2p, prev, it, _ = s
+            free_any = _any(p2o == -1)
+            if rev_every > 0:
+                periodic = (it % rev_every) == (rev_every - 1)
+            else:
+                periodic = zero < 0
+            do_rev = stale(price, o2p) & (~free_any | periodic)
+            fwd = collapsed_bid_round(a, price, pi, p2o, o2p, eps)
+            rev = collapsed_reverse_round(a, price, pi, p2o, o2p, keep2, eps)
+            price2, pi2, p2o2, o2p2 = (jnp.where(do_rev, r, f)
+                                       for r, f in zip(rev, fwd))
+            # two livelock exits, both leaving the last converged scale's
+            # assignment to stand: an unchanged state means the ≥ε
+            # increments fell below f32 resolution, and a state equal to
+            # the one *two* rounds back means a forced fwd/rev interleave
+            # (rev_every) is ping-ponging a contested object ±ε per phase
+            # — neither can ever make further progress
+            p_price, p_pi, p_p2o = prev
+            same1 = (_all(price2 == price) & _all(pi2 == pi)
+                     & _all(p2o2 == p2o))
+            same2 = (_all(price2 == p_price) & _all(pi2 == p_pi)
+                     & _all(p2o2 == p_p2o))
+            new = (price2, pi2, p2o2, o2p2, (price, pi, p2o), it + 1,
+                   (same1 | same2).astype(jnp.int32))
+            on = live(s)
+            return jax.tree.map(lambda n, o: jnp.where(on, n, o), new, s)
+
+        prev0 = (jnp.full_like(price, -1.0), jnp.full_like(pi, -1.0),
+                 jnp.full_like(p2o, -3))
+        price, pi, p2o, o2p, _, it, _ = lax.while_loop(
+            lambda s: jnp.any(live(s)), body,
+            (price, pi, p2o, o2p, prev0, zero, zero))
+        conv = ~_any(p2o == -1) & ~stale(price, o2p)
+        # the finest converged scale's assignment so far, and whether one
+        # of the two finest rungs converged (carried, not stacked: Mosaic
+        # lowers only scans without per-step inputs or outputs)
+        sel = jnp.where(conv, p2o, sel)
+        late = conv & (sc >= n_scales - 2)
+        return (price, pi, p2o, o2p, rounds + it, sel,
+                any_conv | conv.astype(jnp.int32),
+                late_conv | late.astype(jnp.int32))
+
+    (price, _, p2o_last, _, rounds, sel, any_conv, converged) = lax.fori_loop(
+        0, n_scales, run_scale,
+        (price, pi, p2o, o2p, zero, p2o, zero, zero))
+    p2o = jnp.where(any_conv != 0, sel, p2o_last)
+    # a still-free person (nothing converged) is reported at OUT: the
+    # matching stays feasible — every person holds at most one distinct
+    # object throughout — just not certified optimal (converged=False)
+    matched = p2o >= 0
+    total = _sum_in_order(
+        jnp.where(matched, _pick(cbar, jnp.clip(p2o, 0), -1), 0.0))
+    return p2o, total, converged != 0, rounds, price
 
 
 def auction_solve_collapsed(cbar, keep1, keep2, price0=None,
@@ -325,113 +540,14 @@ def auction_solve_collapsed(cbar, keep1, keep2, price0=None,
     ratio the autotuner sweeps); reverse rounds always run once forward
     bidding has no free persons left.
     """
-    k = cbar.shape[-1]
-    if max_rounds is None:
-        max_rounds = default_max_rounds(k)
-    rev_every = int(rev_every)
-    cbar = cbar.astype(jnp.float32)
-    valid = keep1[:, None] & keep2[None, :]
-    c_scale = jnp.maximum(
-        jnp.max(jnp.where(valid, jnp.abs(cbar), 0.0)), 1e-30)
-    a = jnp.where(valid, -(cbar / c_scale), -jnp.inf)
-    idx = jnp.arange(k)
-    eps_ladder = eps0 * eps_factor ** -jnp.arange(n_scales, dtype=jnp.float32)
     if price0 is None:
-        price = jnp.zeros((k,), jnp.float32)
-    else:
-        price = jnp.where(keep2, jnp.maximum(price0.astype(jnp.float32), 0.0),
-                          0.0)
-    # warm start (any nonzero price) skips the annealing ladder: coarse
-    # scales would inflate the already-equilibrated prices and then pay
-    # reverse rounds to re-ground them, so a warm lane runs every scan
-    # iteration at the finest ε instead (auction from arbitrary nonneg
-    # prices + empty assignment preserves ε-CS, so the ε_final optimality
-    # certificate is unchanged; the ladder is purely a cold-start speedup)
-    warm = jnp.any(price > 0.0)
-    eps_ladder = jnp.where(warm, eps_ladder[-1], eps_ladder)
-    # initial profits must over-claim nothing: best attainable value now
-    pi = jnp.maximum(jnp.max(a - price[None, :], axis=-1), 0.0)
-    # invalid persons sit at OUT for good (cbar row is -inf, never bid)
-    p2o = jnp.where(keep1, -1, OUT).astype(jnp.int32)
-    o2p = jnp.full((k,), -1, jnp.int32)
-
-    def run_scale(carry, eps):
-        price, pi, p2o, o2p, rounds = carry
-        # ε-CS partial reset: persons keep their slot (real object or OUT)
-        # only while it is still within eps of their best option at the
-        # new, finer scale; freed persons re-bid, and the objects they
-        # abandon keep their stale prices for the reverse rounds to repair
-        v = a - price[None, :]
-        best = jnp.maximum(jnp.max(v, axis=-1), 0.0)
-        mine = jnp.where(
-            p2o >= 0,
-            jnp.take_along_axis(v, jnp.clip(p2o, 0)[:, None], axis=-1)[:, 0],
-            0.0)                                     # OUT is worth exactly 0
-        keep = (p2o != -1) & (mine >= best - eps)
-        keep = keep | ~keep1
-        p2o = jnp.where(keep, p2o, -1)
-        o2p = jnp.max(jnp.where((p2o[:, None] == idx[None, :]),
-                                idx[:, None], -1), axis=0)
-
-        def cond(s):
-            price, pi, p2o, o2p, prev, it, stalled = s
-            free_any = jnp.any(p2o == -1)
-            stale_any = jnp.any(keep2 & (o2p < 0) & (price > 0.0))
-            return (free_any | stale_any) & (it < max_rounds) & ~stalled
-
-        def body(s):
-            price, pi, p2o, o2p, prev, it, _ = s
-            free_any = jnp.any(p2o == -1)
-            stale_any = jnp.any(keep2 & (o2p < 0) & (price > 0.0))
-            if rev_every > 0:
-                periodic = (it % rev_every) == (rev_every - 1)
-            else:
-                periodic = jnp.bool_(False)
-            do_rev = stale_any & (~free_any | periodic)
-            price2, pi2, p2o2, o2p2 = lax.cond(
-                do_rev,
-                lambda args: collapsed_reverse_round(*args[:-1], keep2,
-                                                     args[-1]),
-                lambda args: collapsed_bid_round(*args),
-                (a, price, pi, p2o, o2p, eps))
-            # two livelock exits, both leaving the last converged scale's
-            # assignment to stand: an unchanged state means the ≥ε
-            # increments fell below f32 resolution, and a state equal to
-            # the one *two* rounds back means a forced fwd/rev interleave
-            # (rev_every) is ping-ponging a contested object ±ε per phase
-            # — neither can ever make further progress
-            p_price, p_pi, p_p2o = prev
-            same1 = (jnp.all(price2 == price) & jnp.all(pi2 == pi)
-                     & jnp.all(p2o2 == p2o))
-            same2 = (jnp.all(price2 == p_price) & jnp.all(pi2 == p_pi)
-                     & jnp.all(p2o2 == p_p2o))
-            return (price2, pi2, p2o2, o2p2, (price, pi, p2o), it + 1,
-                    same1 | same2)
-
-        prev0 = (jnp.full_like(price, -1.0), jnp.full_like(pi, -1.0),
-                 jnp.full_like(p2o, -3))
-        price, pi, p2o, o2p, _, it, _ = lax.while_loop(
-            cond, body,
-            (price, pi, p2o, o2p, prev0, jnp.int32(0), jnp.bool_(False)))
-        conv = (~jnp.any(p2o == -1)
-                & ~jnp.any(keep2 & (o2p < 0) & (price > 0.0)))
-        return (price, pi, p2o, o2p, rounds + it), (p2o, conv)
-
-    (price, _, _, _, rounds), (p2o_s, conv_s) = lax.scan(
-        run_scale, (price, pi, p2o, o2p, jnp.int32(0)), eps_ladder)
-    any_conv = jnp.any(conv_s)
-    converged = jnp.any(conv_s[-2:])
-    last = n_scales - 1 - jnp.argmax(conv_s[::-1])
-    p2o = jnp.where(any_conv, jnp.take(p2o_s, last, axis=0), p2o_s[-1])
-    # a still-free person (nothing converged) is reported at OUT: the
-    # matching stays feasible — every person holds at most one distinct
-    # object throughout — just not certified optimal (converged=False)
-    matched = p2o >= 0
-    total = jnp.sum(jnp.where(
-        matched,
-        jnp.take_along_axis(cbar, jnp.clip(p2o, 0)[:, None], axis=-1)[:, 0],
-        0.0))
-    return p2o.astype(jnp.int32), total, converged, rounds, price
+        price0 = jnp.zeros(keep2.shape, jnp.float32)
+    p2o, total, conv, rounds, price = solve_collapsed_batch(
+        cbar[None], keep1[None], keep2[None], price0[None], eps0=eps0,
+        eps_factor=eps_factor, n_scales=n_scales, max_rounds=max_rounds,
+        rev_every=rev_every)
+    return (p2o[0].astype(jnp.int32), total[0, 0], conv[0, 0],
+            rounds[0, 0], price[0])
 
 
 def expand_collapsed_assignment(p2o, keep1, keep2):
@@ -481,7 +597,7 @@ def auction_lap_pallas(cost: jax.Array, eps0: float = DEFAULT_EPS0,
                        n_scales: int = DEFAULT_N_SCALES,
                        max_rounds: int | None = None,
                        tile_b: int = 1,
-                       interpret: bool = True):
+                       *, interpret: bool):
     """Batched assignment solve: (B, M, M) costs → matchings + totals.
 
     Returns ``(assign (B, M) i32, total (B,) f32, converged (B,) bool,
@@ -531,15 +647,17 @@ def auction_lap_pallas(cost: jax.Array, eps0: float = DEFAULT_EPS0,
 def _collapsed_kernel(cbar_ref, keep1_ref, keep2_ref, price0_ref,
                       p2o_ref, total_ref, conv_ref, rounds_ref, price_ref, *,
                       eps0, eps_factor, n_scales, max_rounds, rev_every):
-    p2o, total, conv, rounds, price = jax.vmap(functools.partial(
-        auction_solve_collapsed, eps0=eps0, eps_factor=eps_factor,
-        n_scales=n_scales, max_rounds=max_rounds, rev_every=rev_every,
-    ))(cbar_ref[...], keep1_ref[...], keep2_ref[...], price0_ref[...])
-    p2o_ref[...] = p2o.astype(jnp.int32)
-    total_ref[...] = total[:, None]
-    conv_ref[...] = conv[:, None]
-    rounds_ref[...] = rounds[:, None].astype(jnp.int32)
-    price_ref[...] = price
+    # per-pair rows arrive as (tile_b, 1, K) blocks (last two dims equal
+    # the array's, as Mosaic requires) and masks as int32 (no bool refs)
+    p2o, total, conv, rounds, price = solve_collapsed_batch(
+        cbar_ref[...], keep1_ref[:, 0, :] != 0, keep2_ref[:, 0, :] != 0,
+        price0_ref[:, 0, :], eps0=eps0, eps_factor=eps_factor,
+        n_scales=n_scales, max_rounds=max_rounds, rev_every=rev_every)
+    p2o_ref[:, 0, :] = p2o
+    total_ref[:, 0, :] = total
+    conv_ref[:, 0, :] = conv.astype(jnp.int32)
+    rounds_ref[:, 0, :] = rounds
+    price_ref[:, 0, :] = price
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -553,7 +671,7 @@ def auction_lap_collapsed_pallas(cbar: jax.Array, keep1: jax.Array,
                                  max_rounds: int | None = None,
                                  rev_every: int = DEFAULT_REV_EVERY,
                                  tile_b: int = 1,
-                                 interpret: bool = True):
+                                 *, interpret: bool):
     """Batched collapsed forward/reverse auction: (B, K, K) reduced costs.
 
     Returns ``(p2o (B, K) i32, total (B,) f32, converged (B,) bool,
@@ -575,13 +693,13 @@ def auction_lap_collapsed_pallas(cbar: jax.Array, keep1: jax.Array,
         max_rounds = default_max_rounds(k)
     bp = -(-b // tile_b) * tile_b
     pad_b = ((0, bp - b),)
-    cbarp = jnp.pad(cbar.astype(jnp.float32), pad_b + ((0, 0), (0, 0)))
-    keep1p = jnp.pad(keep1.astype(jnp.bool_), pad_b + ((0, 0),))
-    keep2p = jnp.pad(keep2.astype(jnp.bool_), pad_b + ((0, 0),))
-    price0p = jnp.pad(price0.astype(jnp.float32), pad_b + ((0, 0),))
-    row_spec = pl.BlockSpec((tile_b, k), lambda i: (i, 0),
+
+    def rows(x, dtype):  # (B, K) -> padded (Bp, 1, K)
+        return jnp.pad(x.astype(dtype), pad_b + ((0, 0),))[:, None, :]
+
+    row_spec = pl.BlockSpec((tile_b, 1, k), lambda i: (i, 0, 0),
                             memory_space=pltpu.VMEM)
-    one_spec = pl.BlockSpec((tile_b, 1), lambda i: (i, 0),
+    one_spec = pl.BlockSpec((tile_b, 1, 1), lambda i: (i, 0, 0),
                             memory_space=pltpu.VMEM)
     p2o, total, conv, rounds, price = pl.pallas_call(
         functools.partial(_collapsed_kernel, eps0=eps0,
@@ -595,13 +713,17 @@ def auction_lap_collapsed_pallas(cbar: jax.Array, keep1: jax.Array,
         ],
         out_specs=[row_spec, one_spec, one_spec, one_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bp, k), jnp.int32),
-            jax.ShapeDtypeStruct((bp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((bp, 1), jnp.bool_),
-            jax.ShapeDtypeStruct((bp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((bp, k), jnp.float32),
+            jax.ShapeDtypeStruct((bp, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((bp, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bp, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((bp, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((bp, 1, k), jnp.float32),
         ],
         interpret=interpret,
         name="auction_lap_collapsed",
-    )(cbarp, keep1p, keep2p, price0p)
-    return (p2o[:b], total[:b, 0], conv[:b, 0], rounds[:b, 0], price[:b])
+    )(jnp.pad(cbar.astype(jnp.float32), pad_b + ((0, 0), (0, 0))),
+      rows(keep1, jnp.int32), rows(keep2, jnp.int32),
+      rows(price0, jnp.float32))
+    p2o, total, conv, rounds, price = (
+        x[:b, 0] for x in (p2o, total, conv, rounds, price))
+    return p2o, total[:, 0], conv[:, 0] != 0, rounds[:, 0], price

@@ -35,7 +35,7 @@ def _kernel(a_uw_ref, a_wv_ref, a_uv_ref, out_ref, acc_ref, *, n_w: int):
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def common_neighbors_pallas(
-    adj: jax.Array, tile: int = 128, interpret: bool = True
+    adj: jax.Array, tile: int = 128, *, interpret: bool
 ) -> jax.Array:
     """cn[b, u, v] = |N(u) ∩ N(v)| on edges.  adj (B,N,N) bool -> (B,N,N) i32."""
     b, n, _ = adj.shape

@@ -52,7 +52,7 @@ def domination_pallas(
     tile_u: int = 128,
     tile_v: int = 128,
     tile_w: int = 128,
-    interpret: bool = True,
+    *, interpret: bool,
 ) -> jax.Array:
     """dom[b, u, v] = "v dominates u".  adj (B,N,N) bool, mask (B,N) bool."""
     b, n, _ = adj.shape

@@ -30,150 +30,118 @@ WORD = 32
 
 
 def _low_of(col: jax.Array) -> jax.Array:
-    """col: (1, W) u32 -> highest set bit index or -1."""
-    w = col.shape[-1]
-    nz = col != 0
-    iota = lax.broadcasted_iota(jnp.int32, (1, w), 1)
-    widx = jnp.max(jnp.where(nz, iota, -1))
-    word = jnp.max(jnp.where(iota == widx, col, jnp.uint32(0)))
-    bit = 31 - lax.clz(word).astype(jnp.int32)
-    return jnp.where(widx >= 0, widx * WORD + bit, -1)
+    """col: (1, W) i32 packed words -> highest set bit index or -1.
 
-
-def _reduce_columns(s, get_col, put_col, get_owner, put_owner,
-                    put_positive):
-    """The column reduction loop, parameterized over ref accessors.
-
-    One definition serves both the flat single-matrix kernel (refs
-    ``(S, W)``) and the grid-batched kernel (refs ``(1, S, W)``, one
-    complex per grid step): the accessors close over the refs and hide
-    the leading-axis indexing difference.
+    The per-word top bit is taken elementwise (``clz`` on the vector) so
+    the only cross-lane step is one signed max — no unsigned reduction
+    and no scalar ``clz``, neither of which Mosaic lowers.
     """
+    iota = lax.broadcasted_iota(jnp.int32, col.shape, 1)
+    top = (WORD - 1) - lax.clz(col)
+    return jnp.max(jnp.where(col != 0, iota * WORD + top, -1))
 
-    def col_body(j, _):
+
+def _reduce_columns(bm_ref, owner_ref, pos_ref, row):
+    """The column reduction loop over VMEM refs.
+
+    ``row(j)`` indexes packed column ``j`` of ``bm_ref``; ``owner_ref``
+    ``(.., 1, R)`` and ``pos_ref`` ``(.., 1, S)`` are int32 lane vectors.
+    Owner lookups and updates are one-hot selects over the whole lane
+    vector: a dynamic single-lane access is what Mosaic refuses, a
+    masked select of a few vregs is what it does best.
+    """
+    s = pos_ref.shape[-1]
+    r = owner_ref.shape[-1]
+    iota_r = lax.broadcasted_iota(jnp.int32, owner_ref.shape, owner_ref.ndim - 1)
+    iota_s = lax.broadcasted_iota(jnp.int32, pos_ref.shape, pos_ref.ndim - 1)
+
+    def col_body(j, carry):
         def w_cond(cs):
-            _, done, _ = cs
-            return ~done
+            return ~cs[1]
 
         def w_body(cs):
             col, _, _ = cs
             l = _low_of(col)
+            # owner[l], or -1 when l == -1 (owner entries are >= -1)
+            p = jnp.max(jnp.where(iota_r == l, owner_ref[...], -1))
+            done = (l < 0) | (p < 0)
 
-            def no_bits(col):
-                return col, jnp.array(True), jnp.int32(-1)
+            def xor(col):
+                return col ^ bm_ref[row(p)]
 
-            def has_bits(col):
-                p = get_owner(l)
-
-                def claim(col):
-                    return col, jnp.array(True), l
-
-                def xor(col):
-                    return col ^ get_col(p), jnp.array(False), jnp.int32(-1)
-
-                return lax.cond(p < 0, claim, xor, col)
-
-            return lax.cond(l < 0, no_bits, has_bits, col)
+            col = lax.cond(done, lambda c: c, xor, col)
+            return col, done, jnp.where(p < 0, l, -1)
 
         col, _, claimed = lax.while_loop(
-            w_cond, w_body, (get_col(j), jnp.array(False), jnp.int32(-1))
-        )
-        put_col(j, col)
-
-        @pl.when(claimed >= 0)
-        def _claim():
-            put_owner(claimed, j)
-
-        put_positive(j, claimed < 0)
-        return 0
+            w_cond, w_body, (bm_ref[row(j)], jnp.bool_(False), jnp.int32(-1)))
+        bm_ref[row(j)] = col
+        # claimed == -1 matches no lane, so a zero column leaves owner as is
+        owner_ref[...] = jnp.where(iota_r == claimed, j, owner_ref[...])
+        pos_ref[...] = jnp.where(iota_s == j, (claimed < 0).astype(jnp.int32),
+                                 pos_ref[...])
+        return carry
 
     lax.fori_loop(0, s, col_body, 0)
 
 
-def _kernel(b_ref, bm_ref, owner_ref, positive_ref):
-    s, w = b_ref.shape
-    r = owner_ref.shape[0]  # rows may differ from columns (block reduction)
+def _kernel(b_ref, bm_ref, owner_ref, pos_ref):
     bm_ref[...] = b_ref[...]
-    owner_ref[...] = jnp.full((r,), -1, jnp.int32)
-    positive_ref[...] = jnp.zeros((s,), jnp.bool_)
-    _reduce_columns(
-        s,
-        get_col=lambda j: pl.load(bm_ref, (pl.dslice(j, 1), slice(None))),
-        put_col=lambda j, col: pl.store(
-            bm_ref, (pl.dslice(j, 1), slice(None)), col),
-        get_owner=lambda l: pl.load(owner_ref, (pl.dslice(l, 1),))[0],
-        put_owner=lambda l, j: pl.store(
-            owner_ref, (pl.dslice(l, 1),), jnp.full((1,), j, jnp.int32)),
-        put_positive=lambda j, pos: pl.store(
-            positive_ref, (pl.dslice(j, 1),),
-            jnp.full((1,), pos, jnp.bool_)),
-    )
+    owner_ref[...] = jnp.full(owner_ref.shape, -1, jnp.int32)
+    pos_ref[...] = jnp.zeros(pos_ref.shape, jnp.int32)
+    _reduce_columns(bm_ref, owner_ref, pos_ref,
+                    row=lambda j: (pl.ds(j, 1), slice(None)))
 
 
-def _batch_kernel(b_ref, bm_ref, owner_ref, positive_ref):
-    _, s, w = b_ref.shape
-    r = owner_ref.shape[-1]
+def _batch_kernel(b_ref, bm_ref, owner_ref, pos_ref):
     bm_ref[...] = b_ref[...]
-    owner_ref[...] = jnp.full((1, r), -1, jnp.int32)
-    positive_ref[...] = jnp.zeros((1, s), jnp.bool_)
-    z = pl.dslice(0, 1)
-    _reduce_columns(
-        s,
-        get_col=lambda j: pl.load(
-            bm_ref, (z, pl.dslice(j, 1), slice(None)))[0],
-        put_col=lambda j, col: pl.store(
-            bm_ref, (z, pl.dslice(j, 1), slice(None)), col[None]),
-        get_owner=lambda l: pl.load(owner_ref, (z, pl.dslice(l, 1)))[0, 0],
-        put_owner=lambda l, j: pl.store(
-            owner_ref, (z, pl.dslice(l, 1)), jnp.full((1, 1), j, jnp.int32)),
-        put_positive=lambda j, pos: pl.store(
-            positive_ref, (z, pl.dslice(j, 1)),
-            jnp.full((1, 1), pos, jnp.bool_)),
-    )
+    owner_ref[...] = jnp.full(owner_ref.shape, -1, jnp.int32)
+    pos_ref[...] = jnp.zeros(pos_ref.shape, jnp.int32)
+    _reduce_columns(bm_ref, owner_ref, pos_ref,
+                    row=lambda j: (0, pl.ds(j, 1), slice(None)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "n_rows"))
-def gf2_reduce_pallas(b: jax.Array, interpret: bool = True,
+def gf2_reduce_pallas(b: jax.Array, *, interpret: bool,
                       n_rows: int | None = None):
     """Reduce one packed boundary matrix.  b: (S, W) uint32.
 
     Returns (reduced_matrix, owner, positive) — owner[i] = killing column of
     row (simplex) i or -1; positive[j] = column j reduced to zero.  n_rows
     sizes the owner vector for rectangular per-dimension blocks (defaults to
-    the square case n_rows = S).
+    the square case n_rows = S).  The kernel works on int32 words (a
+    bitcast, free) and int32 lane vectors for owner/positive.
     """
     s, w = b.shape
     r = s if n_rows is None else n_rows
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     bm, owner, positive = pl.pallas_call(
         _kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
+        in_specs=[vmem],
+        out_specs=[vmem, vmem, vmem],
         out_shape=[
-            jax.ShapeDtypeStruct((s, w), jnp.uint32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((s,), jnp.bool_),
+            jax.ShapeDtypeStruct((s, w), jnp.int32),
+            jax.ShapeDtypeStruct((1, r), jnp.int32),
+            jax.ShapeDtypeStruct((1, s), jnp.int32),
         ],
         interpret=interpret,
         name="gf2_boundary_reduce",
-    )(b)
-    return bm, owner, positive
+    )(lax.bitcast_convert_type(b, jnp.int32))
+    return (lax.bitcast_convert_type(bm, jnp.uint32), owner[0],
+            positive[0] != 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "n_rows"))
-def gf2_reduce_batch_pallas(b: jax.Array, interpret: bool = True,
+def gf2_reduce_batch_pallas(b: jax.Array, *, interpret: bool,
                             n_rows: int | None = None):
     """Grid-batched reduction of (B, S, W) packed matrices.
 
     One grid step per complex (block ``(1, S, W)`` resident in VMEM) —
-    the alternative to vmapping :func:`gf2_reduce_pallas` over the batch
-    (which batches every column op across complexes instead).  Which
-    wins is device-dependent; ``python -m repro.perfgate tune`` times
-    both and pins the winner as the ``gf2_reduce.batch_mode`` tile
-    (``repro.kernels.ops.gf2_reduce_batch`` consults it).
+    the alternative to vmapping :func:`gf2_reduce_pallas` over the batch.
+    Which wins is device-dependent; ``python -m repro.perfgate tune``
+    times both and pins the winner as the ``gf2_reduce.batch_mode`` tile
+    (``repro.kernels.ops.gf2_reduce_batch`` consults it).  owner/positive
+    are ``(B, 1, R)`` / ``(B, 1, S)`` inside the kernel so every block's
+    last two dims equal the array's.
     """
     bsz, s, w = b.shape
     r = s if n_rows is None else n_rows
@@ -185,17 +153,18 @@ def gf2_reduce_batch_pallas(b: jax.Array, interpret: bool = True,
         out_specs=[
             pl.BlockSpec((1, s, w), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, r), lambda i: (i, 0),
+            pl.BlockSpec((1, 1, r), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, s), lambda i: (i, 0),
+            pl.BlockSpec((1, 1, s), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, w), jnp.uint32),
-            jax.ShapeDtypeStruct((bsz, r), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, s), jnp.bool_),
+            jax.ShapeDtypeStruct((bsz, s, w), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, 1, r), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, 1, s), jnp.int32),
         ],
         interpret=interpret,
         name="gf2_boundary_reduce_batch",
-    )(b)
-    return bm, owner, positive
+    )(lax.bitcast_convert_type(b, jnp.int32))
+    return (lax.bitcast_convert_type(bm, jnp.uint32), owner[:, 0],
+            positive[:, 0] != 0)

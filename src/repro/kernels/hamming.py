@@ -64,7 +64,7 @@ def hamming_scan_pallas(
     codes_db: jax.Array,
     tile_q: int = 8,
     tile_n: int = 128,
-    interpret: bool = True,
+    *, interpret: bool,
 ) -> jax.Array:
     """(Q, W) × (N, W) packed uint32 codes → (Q, N) int32 masked Hamming."""
     q, w = codes_q.shape

@@ -45,7 +45,7 @@ def kcore_peel_pallas(
     k: jax.Array | int,
     tile_u: int = 128,
     tile_w: int = 128,
-    interpret: bool = True,
+    *, interpret: bool,
 ) -> jax.Array:
     """One peel sweep.  adj (B,N,N) bool, alive (B,N) bool, k scalar."""
     b, n, _ = adj.shape

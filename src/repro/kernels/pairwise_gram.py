@@ -48,7 +48,7 @@ def pairwise_l1_pallas(
     tile_m: int = 8,
     tile_n: int = 128,
     tile_d: int = 128,
-    interpret: bool = True,
+    *, interpret: bool,
 ) -> jax.Array:
     """(M, D) × (N, D) → (M, N) f32 pairwise-L1 distance (Gram) matrix."""
     m, d = x.shape

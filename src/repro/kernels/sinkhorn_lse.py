@@ -74,9 +74,9 @@ def _lse_kernel(xp_ref, yp_ref, dual_ref, logw_ref, e_ref, out_ref,
         s_ref[...] = jnp.zeros_like(s_ref)
 
     c = _cost_block(xp_ref[0], yp_ref[0])
-    e = e_ref[0, 0]
+    e = e_ref[0]                                                  # (1, 1)
     # identical op order to the dense path: logw + (dual − c)/ε
-    z = logw_ref[...] + (dual_ref[...] - c) / e
+    z = logw_ref[0] + (dual_ref[0] - c) / e
     m_blk = jnp.max(z, axis=-1)                                   # (TM,)
     s_blk = jnp.sum(_safe_exp(z - m_blk[:, None]), axis=-1)
     m_old, s_old = m_ref[0], s_ref[0]
@@ -89,7 +89,7 @@ def _lse_kernel(xp_ref, yp_ref, dual_ref, logw_ref, e_ref, out_ref,
     @pl.when(j == n_j - 1)
     def _fin():
         out_ref[...] = jnp.where(jnp.isfinite(m_new),
-                                 m_new + jnp.log(s_new), -jnp.inf)[None]
+                                 m_new + jnp.log(s_new), -jnp.inf)[None, None]
 
 
 @functools.partial(jax.jit,
@@ -97,7 +97,7 @@ def _lse_kernel(xp_ref, yp_ref, dual_ref, logw_ref, e_ref, out_ref,
 def sinkhorn_lse_pallas(xp: jax.Array, yp: jax.Array, dual: jax.Array,
                         logw: jax.Array, e_t: jax.Array,
                         tile_m: int = 128, tile_n: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        *, interpret: bool) -> jax.Array:
     """(B, M) online-LSE: ``out[b, i] = LSE_j(logw[b,j] + (dual[b,j] − c_ij)/ε_b)``.
 
     ``xp``/``yp``: (B, 8, M)/(B, 8, N) coordinate planes; ``dual``/``logw``:
@@ -110,9 +110,11 @@ def sinkhorn_lse_pallas(xp: jax.Array, yp: jax.Array, dual: jax.Array,
     np_ = -(-n // tile_n) * tile_n
     xpp = jnp.pad(xp, ((0, 0), (0, 0), (0, mp - m)))
     ypp = jnp.pad(yp, ((0, 0), (0, 0), (0, np_ - n)))
-    dualp = jnp.pad(dual, ((0, 0), (0, np_ - n)))
+    # per-pair rows ride as (B, 1, N): every block's last two dims are then
+    # (1, tile) of a (1, N) array, the layout Mosaic accepts
+    dualp = jnp.pad(dual, ((0, 0), (0, np_ - n)))[:, None]
     logwp = jnp.pad(logw, ((0, 0), (0, np_ - n)),
-                    constant_values=-jnp.inf)
+                    constant_values=-jnp.inf)[:, None]
 
     grid = (b, mp // tile_m, np_ // tile_n)
     out = pl.pallas_call(
@@ -123,24 +125,24 @@ def sinkhorn_lse_pallas(xp: jax.Array, yp: jax.Array, dual: jax.Array,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 8, tile_n), lambda b, i, j: (b, 0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_n), lambda b, i, j: (b, j),
+            pl.BlockSpec((1, 1, tile_n), lambda b, i, j: (b, 0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_n), lambda b, i, j: (b, j),
+            pl.BlockSpec((1, 1, tile_n), lambda b, i, j: (b, 0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0),
+            pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, tile_m), lambda b, i, j: (b, i),
+        out_specs=pl.BlockSpec((1, 1, tile_m), lambda b, i, j: (b, 0, i),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, mp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, mp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, tile_m), jnp.float32),
                         pltpu.VMEM((1, tile_m), jnp.float32)],
         interpret=interpret,
         name="sinkhorn_lse_blocked",
     )(xpp.astype(jnp.float32), ypp.astype(jnp.float32),
       dualp.astype(jnp.float32), logwp.astype(jnp.float32),
-      e_t.astype(jnp.float32))
-    return out[:, :m]
+      e_t.astype(jnp.float32)[:, None])
+    return out[:, 0, :m]
 
 
 def _pair_sum_kernel(xp_ref, yp_ref, f_ref, g_ref, la_ref, lb_ref, e_ref,
@@ -152,20 +154,20 @@ def _pair_sum_kernel(xp_ref, yp_ref, f_ref, g_ref, la_ref, lb_ref, e_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     c = _cost_block(xp_ref[0], yp_ref[0])
-    la_col = la_ref[...].T                                         # (TM, 1)
-    lb_row = lb_ref[...]                                           # (1, TN)
+    la_col = la_ref[0].T                                           # (TM, 1)
+    lb_row = lb_ref[0]                                             # (1, TN)
     pair = jnp.isfinite(la_col) & jnp.isfinite(lb_row)
     if plan:
-        e = e_ref[0, 0]
-        z = la_col + lb_row + (f_ref[...].T + g_ref[...] - c) / e
+        e = e_ref[0]                                               # (1, 1)
+        z = la_col + lb_row + (f_ref[0].T + g_ref[0] - c) / e
         add = jnp.where(pair, jnp.exp(z) * c, 0.0)
     else:
         add = jnp.where(pair, c, 0.0)
-    acc_ref[0, 0] += jnp.sum(add, axis=(0, 1))
+    acc_ref[...] += jnp.sum(add, axis=(0, 1), keepdims=True)
 
     @pl.when((i == n_i - 1) & (j == n_j - 1))
     def _fin():
-        out_ref[...] = acc_ref[...]
+        out_ref[...] = acc_ref[...][None]
 
 
 @functools.partial(jax.jit,
@@ -175,7 +177,7 @@ def sinkhorn_pair_sum_pallas(xp: jax.Array, yp: jax.Array, f: jax.Array,
                              log_b: jax.Array, e_t: jax.Array,
                              mode: str = "plan", tile_m: int = 128,
                              tile_n: int = 128,
-                             interpret: bool = True) -> jax.Array:
+                             *, interpret: bool) -> jax.Array:
     """(B,) masked pair reduction over the on-the-fly cost.
 
     ``mode="plan"``: Σ over valid pairs of ``exp(log_a + log_b +
@@ -191,10 +193,12 @@ def sinkhorn_pair_sum_pallas(xp: jax.Array, yp: jax.Array, f: jax.Array,
     np_ = -(-n // tile_n) * tile_n
     xpp = jnp.pad(xp, ((0, 0), (0, 0), (0, mp - m)))
     ypp = jnp.pad(yp, ((0, 0), (0, 0), (0, np_ - n)))
-    fp = jnp.pad(f, ((0, 0), (0, mp - m)))
-    gp = jnp.pad(g, ((0, 0), (0, np_ - n)))
-    lap = jnp.pad(log_a, ((0, 0), (0, mp - m)), constant_values=-jnp.inf)
-    lbp = jnp.pad(log_b, ((0, 0), (0, np_ - n)), constant_values=-jnp.inf)
+    fp = jnp.pad(f, ((0, 0), (0, mp - m)))[:, None]
+    gp = jnp.pad(g, ((0, 0), (0, np_ - n)))[:, None]
+    lap = jnp.pad(log_a, ((0, 0), (0, mp - m)),
+                  constant_values=-jnp.inf)[:, None]
+    lbp = jnp.pad(log_b, ((0, 0), (0, np_ - n)),
+                  constant_values=-jnp.inf)[:, None]
 
     grid = (b, mp // tile_m, np_ // tile_n)
     out = pl.pallas_call(
@@ -206,25 +210,25 @@ def sinkhorn_pair_sum_pallas(xp: jax.Array, yp: jax.Array, f: jax.Array,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 8, tile_n), lambda b, i, j: (b, 0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_m), lambda b, i, j: (b, i),
+            pl.BlockSpec((1, 1, tile_m), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_n), lambda b, i, j: (b, j),
+            pl.BlockSpec((1, 1, tile_n), lambda b, i, j: (b, 0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_m), lambda b, i, j: (b, i),
+            pl.BlockSpec((1, 1, tile_m), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_n), lambda b, i, j: (b, j),
+            pl.BlockSpec((1, 1, tile_n), lambda b, i, j: (b, 0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0),
+            pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, i, j: (b, 0),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)],
         interpret=interpret,
         name=f"sinkhorn_pair_sum_{mode}",
     )(xpp.astype(jnp.float32), ypp.astype(jnp.float32),
       fp.astype(jnp.float32), gp.astype(jnp.float32),
       lap.astype(jnp.float32), lbp.astype(jnp.float32),
-      e_t.astype(jnp.float32))
-    return out[:, 0]
+      e_t.astype(jnp.float32)[:, None])
+    return out[:, 0, 0]

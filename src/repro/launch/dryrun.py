@@ -7,6 +7,11 @@ Usage:
 
 The XLA_FLAGS lines below MUST precede any jax import (device count locks at
 first init); only this module sets it — tests/benches see 1 device.
+
+CPU only, and not run on a TPU host: it belongs to the language-model
+scaffold, forces 512 host devices and ``--all`` spawns one child process
+per cell, while a TPU chip can be held by one process at a time.  The
+served path's chip check is ``chip_smoke.py`` (one process).
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"

@@ -24,10 +24,16 @@ def make_serve_mesh(n_devices: int | None = None, *, multi_pod: bool = False):
     repro/serve/topo_serve.py).  Default: every visible device on one axis.
     """
     n = n_devices if n_devices is not None else len(jax.devices())
+    # Auto axes: the shard_map output is then an ordinary sharded array that
+    # the serve layer can index per graph (``jax.make_mesh`` defaults to
+    # Explicit axes, whose arrays refuse an unannotated ``x[i]``)
     if multi_pod:
         assert n % 2 == 0, f"multi_pod serve mesh needs even device count, got {n}"
-        return jax.make_mesh((2, n // 2), ("pod", "data"))
-    return jax.make_mesh((n,), ("data",))
+        shape, axes = (2, n // 2), ("pod", "data")
+    else:
+        shape, axes = (n,), ("data",)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_index_mesh(n_devices: int | None = None, rows: int | None = None):

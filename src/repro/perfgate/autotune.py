@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, tuning
+from repro.kernels.ops import _interpret
 from repro.kernels.auction_lap import (
     auction_lap_collapsed_pallas,
     auction_lap_pallas,
@@ -137,10 +138,6 @@ def tune(only: list[str] | None = None, quick: bool = True,
 
 # ------------------------------------------------------------- the kernels
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _gram_workload(quick: bool):
     m, d = (64, 256) if quick else (256, 512)
     x = jax.random.normal(jax.random.PRNGKey(7), (m, d), jnp.float32)
@@ -153,7 +150,7 @@ register_tunable(KernelTunable(
            "tile_d": (128, 256)},
     make_workload=_gram_workload,
     time_config=lambda x, c, r: _timed(
-        pairwise_l1_pallas, x, x, interpret=_interp(), repeats=r, **c),
+        pairwise_l1_pallas, x, x, interpret=_interpret(), repeats=r, **c),
     workload_desc=lambda q: "G64_D256" if q else "G256_D512",
 ))
 
@@ -174,7 +171,7 @@ register_tunable(KernelTunable(
     space={"tile_q": (8, 16, 32), "tile_n": (128, 256, 512)},
     make_workload=_hamming_workload,
     time_config=lambda w, c, r: _timed(
-        hamming_scan_pallas, *w, interpret=_interp(), repeats=r, **c),
+        hamming_scan_pallas, *w, interpret=_interpret(), repeats=r, **c),
     workload_desc=lambda q: "Q16_N4096_W4" if q else "Q16_N32768_W4",
 ))
 
@@ -200,7 +197,7 @@ register_tunable(KernelTunable(
     make_workload=_sinkhorn_workload,
     time_config=lambda w, c, r: _timed(
         sinkhorn_lse_pallas, *w, tile_m=c["tile"], tile_n=c["tile"],
-        interpret=_interp(), repeats=r),
+        interpret=_interpret(), repeats=r),
     workload_desc=lambda q: "B2_M256" if q else "B4_M512",
 ))
 
@@ -216,7 +213,7 @@ register_tunable(KernelTunable(
     space={"tile_b": (1, 2, 4, 8)},
     make_workload=_auction_workload,
     time_config=lambda c3, c, r: _timed(
-        auction_lap_pallas, c3, tile_b=c["tile_b"], interpret=_interp(),
+        auction_lap_pallas, c3, tile_b=c["tile_b"], interpret=_interpret(),
         repeats=r),
     workload_desc=lambda q: "B8_M16" if q else "B32_M16",
 ))
@@ -259,18 +256,18 @@ def _time_collapsed(w, config, repeats):
     if config["collapse"] == "off":
         # the legacy expanded path ignores rev_every (forward-only solver)
         return _timed(auction_lap_pallas, expanded, tile_b=config["tile_b"],
-                      interpret=_interp(), repeats=repeats)
+                      interpret=_interpret(), repeats=repeats)
     t = _timed(
         auction_lap_collapsed_pallas, cbar, keep1, keep2,
         jnp.zeros_like(cbar[..., 0]), tile_b=config["tile_b"],
-        rev_every=config["rev_every"], interpret=_interp(), repeats=repeats)
+        rev_every=config["rev_every"], interpret=_interpret(), repeats=repeats)
     # a config that trades convergence for wall time is disqualified — an
     # unconverged lane means uncertified (possibly wrong) distances and a
     # price the serve-level warm-start cache must refuse to store
     _, _, conv, _, _ = auction_lap_collapsed_pallas(
         cbar, keep1, keep2, jnp.zeros_like(cbar[..., 0]),
         tile_b=config["tile_b"], rev_every=config["rev_every"],
-        interpret=_interp())
+        interpret=_interpret())
     if not bool(jnp.all(conv)):
         return float("inf")
     return t
@@ -306,7 +303,7 @@ def _time_gf2(b3, config, repeats):
     mode = config["batch_mode"]
     if mode == "grid":
         return _timed(lambda x: gf2_reduce_batch_pallas(
-            x, interpret=_interp()), b3, repeats=repeats)
+            x, interpret=_interpret()), b3, repeats=repeats)
     return _timed(
         jax.jit(jax.vmap(lambda bb: ops.gf2_reduce(bb))), b3,
         repeats=repeats)
