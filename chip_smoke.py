@@ -1,6 +1,6 @@
 """Bring-up smoke test: the served path on one TPU chip, checked end to end.
 
-    python chip_smoke.py [--seed N]        # one chip: the four phases below
+    python chip_smoke.py [--seed N]        # one chip: the five phases below
     python chip_smoke.py --four-chips      # 4 chips: the two mesh paths only
 
 One process drives every phase through the public entry points (TopoServe,
@@ -13,11 +13,15 @@ StreamServe, SimilarityServe, the plan cache); all data is generated from
    batch of 256.  Every served diagram must be bit-identical to a direct
    ``topological_signature`` on the same packed batch, and a sample per
    bucket must match the pure-Python reference ``persistence_ref``.
-2. TopoServe, two phase (``repack="on"``): the same traffic; every graph's
+2. TopoServe with the Pallas GF(2) reducer (``reducer="pallas"``) on one
+   full top-bucket batch: bit-identical to phase 1; the kernel's "vmap" and
+   "grid" batch modes on that batch's boundary blocks must equal the jnp
+   ``reduce_packed``.
+3. TopoServe, two phase (``repack="on"``): the same traffic; every graph's
    guaranteed-dimension pairs must equal phase 1's exactly.
-3. StreamServe: one 64-graph ego-decay session; after every update step the
+4. StreamServe: one 64-graph ego-decay session; after every update step the
    session's pairs must equal a from-scratch ``topological_signature``.
-4. SimilarityServe over a ShardedIndex (``coarse="lsh"``) holding a corpus
+5. SimilarityServe over a ShardedIndex (``coarse="lsh"``) holding a corpus
    of >= 100k diagrams: ids must equal an unsharded TopoIndex query and
    distances a NumPy L1 over the same embeddings.
 
@@ -40,6 +44,8 @@ import time
 import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
+NETWORK = "CA-CondMat"  # Table-1 surrogate whose 1-hop ego nets are served
+N_PAD = 4096            # its order: 4,096 centres, top-ups fill the rest
 BATCH = 256          # TopoServe max_batch = pad_batch_to: full bucket width
 REF_PER_BUCKET = 32  # graphs per bucket checked against persistence_ref
 CORPUS = 102_400     # SimilarityServe / ShardedIndex corpus (diagrams)
@@ -108,7 +114,7 @@ def peak_bytes():
 
 # ------------------------------------------------------------------ traffic
 
-def ego_traffic(seed: int, network: str, n_pad: int, router):
+def ego_traffic(seed: int, router):
     """Ego-net requests of one Table-1 surrogate, and how many exceed the
     top rung: those ``router.bucket_for`` rejects, and those with more
     vertices than the top rung, are counted, not served."""
@@ -118,7 +124,7 @@ def ego_traffic(seed: int, network: str, n_pad: int, router):
     from repro.data.ego import ego_batch, ego_sizes
     from repro.data.graphs import load_large_network
 
-    g = load_large_network(network, jax.random.PRNGKey(seed), n_pad=n_pad)
+    g = load_large_network(NETWORK, jax.random.PRNGKey(seed), n_pad=N_PAD)
     adj, f, mask = g.adj[0], g.f[0], g.mask[0]
     top = max(router.config.buckets).n_pad
     sizes = np.asarray(ego_sizes(adj))
@@ -265,6 +271,73 @@ def phase_topo_single(traffic, watch):
         drain_wall_s=round(wall, 3), peak_bytes=peak_bytes(),
         **watch.since(mark))
     return results
+
+
+def phase_topo_pallas(traffic, single, watch):
+    """The Pallas GF(2) reducer on one full top-bucket batch, compiled.
+
+    Served with ``reducer="pallas"`` (the one-matrix kernel, batched by
+    vmap), every diagram must be bit-identical to phase 1's jnp-reduced
+    one.  The batch's own per-dimension boundary blocks (unreduced graphs,
+    so the kernel sees the largest columns) then go through both batch
+    modes of ``ops.gf2_reduce_batch``, "vmap" and "grid", and each must
+    give the (owner, positive) of the jnp ``reduce_packed``.  Only the top
+    bucket runs: each bucket's Pallas pipeline is another cold compile.
+    """
+    from functools import partial
+
+    import jax
+
+    from repro.core.filtration import build_filtered_complex
+    from repro.core.persistence_jax import (
+        diagrams_bitwise_equal,
+        pack_boundary_blocks,
+        reduce_packed,
+    )
+    from repro.kernels import ops
+    from repro.serve import TopoServe, TopoServeConfig
+    from repro.serve.topo_serve import DEFAULT_BUCKETS, pack_requests
+
+    mark = watch.mark()
+    top = DEFAULT_BUCKETS[-1]
+    idx = [i for i, r in enumerate(traffic) if r["bucket"] == top][:BATCH]
+    reqs = [traffic[i] for i in idx]
+    require(len(reqs) == BATCH, f"top bucket holds {len(reqs)} graphs")
+    cfg = TopoServeConfig(buckets=(top,), max_batch=BATCH,
+                          pad_batch_to=BATCH, reducer="pallas",
+                          record_batches=True)
+    srv = TopoServe(cfg)
+    _, served = serve_all(srv, reqs)
+    require(srv.stats["batches"] == 1, "the Pallas batch was split")
+    for i, d in zip(idx, served):
+        require(diagrams_bitwise_equal(d, single[i]),
+                f"graph {i}: Pallas-reduced diagram differs from jnp")
+
+    # rows of each dimension's block: vertices, edges, triangles (dim 1)
+    caps = [top.n_pad, top.edge_cap, top.tri_cap]
+
+    def blocks_of(adj, mask, f):
+        fc = build_filtered_complex(adj, mask, f, cfg.dim, top.edge_cap,
+                                    top.tri_cap, cfg.quad_cap, cfg.sublevel)
+        return pack_boundary_blocks(fc, caps)[0]
+
+    g = pack_requests(srv.executed_batches[0][1], top)
+    blocks = jax.jit(jax.vmap(blocks_of))(g.adj, g.mask, g.f)
+    shapes = []
+    for rows, b in zip(caps, blocks):
+        want = jax.jit(jax.vmap(partial(reduce_packed, n_rows=rows)))(b)
+        for mode in ("vmap", "grid"):
+            got = jax.jit(partial(ops.gf2_reduce_batch, n_rows=rows,
+                                  batch_mode=mode))(b)
+            for name, x, y in zip(("owner", "positive"), got, want):
+                require(np.array_equal(np.asarray(x), np.asarray(y)),
+                        f"gf2_reduce {mode} {b.shape}: {name} differs "
+                        "from reduce_packed")
+        shapes.append(list(b.shape))
+    log("topo_pallas", graphs=len(reqs), bucket=f"n{top.n_pad}",
+        served_bitwise=len(served), kernel_blocks=shapes,
+        kernel_modes=["vmap", "grid"], peak_bytes=peak_bytes(),
+        **watch.since(mark))
 
 
 def phase_topo_repack(traffic, single, watch):
@@ -460,10 +533,6 @@ def phase_four_chips(seed: int, traffic, watch):
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--network", default="CA-CondMat",
-                    help="Table-1 surrogate whose ego nets are served")
-    ap.add_argument("--n-pad", type=int, default=4096,
-                    help="order of the surrogate network")
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the 4-chip mesh paths")
     args = ap.parse_args()
@@ -493,21 +562,22 @@ def main() -> int:
 
     t0 = time.perf_counter()
     router = TopoServe(TopoServeConfig())
-    traffic, over_top = ego_traffic(args.seed, args.network, args.n_pad,
-                                    router)
+    traffic, over_top = ego_traffic(args.seed, router)
     n_ego = len(traffic)
     top_up(traffic, router, args.seed)
     per_bucket = {f"n{b.n_pad}": {
         s: sum(1 for r in traffic if r["bucket"] == b and r["source"] == s)
         for s in ("ego", "topup")} for b in router.config.buckets}
-    log("traffic", network=args.network, n_pad=args.n_pad, ego_nets=n_ego,
+    log("traffic", network=NETWORK, n_pad=N_PAD, ego_nets=n_ego,
         over_top_rung=over_top, topup=len(traffic) - n_ego,
-        per_bucket=per_bucket, setup_s=round(time.perf_counter() - t0, 3))
+        per_bucket=per_bucket, setup_s=round(time.perf_counter() - t0, 3),
+        peak_bytes=peak_bytes())
 
     if args.four_chips:
         phase_four_chips(args.seed, traffic, watch)
     else:
         single = phase_topo_single(traffic, watch)
+        phase_topo_pallas(traffic, single, watch)
         phase_topo_repack(traffic, single, watch)
         phase_stream(args.seed, watch)
         phase_similarity(args.seed, traffic, watch)

@@ -261,16 +261,6 @@ def _pick(x, j, axis):
     return jnp.max(jnp.where(hit, x, -jnp.inf), axis=axis)
 
 
-def _any(x):
-    """Per-pair ``any`` over the last axis, kept as a ``(..., 1)`` column."""
-    return jnp.any(x, axis=-1, keepdims=True)
-
-
-def _all(x):
-    """Per-pair ``all`` over the last axis, kept as a ``(..., 1)`` column."""
-    return jnp.all(x, axis=-1, keepdims=True)
-
-
 def _col(x):
     """(..., K) -> (..., K, 1); bools go through int32, since Mosaic has
     no lane-to-sublane reshape of an i1 vector."""
@@ -423,7 +413,8 @@ def solve_collapsed_batch(cbar, keep1, keep2, price0,
     # iteration at the finest ε instead (auction from arbitrary nonneg
     # prices + empty assignment preserves ε-CS, so the ε_final optimality
     # certificate is unchanged; the ladder is purely a cold-start speedup)
-    eps_ladder = jnp.where(_any(price > 0.0), ladder[-1], eps_ladder)
+    eps_ladder = jnp.where(jnp.any(price > 0.0, axis=-1, keepdims=True),
+                           ladder[-1], eps_ladder)
     # initial profits must over-claim nothing: best attainable value now
     pi = jnp.maximum(jnp.max(a - price[..., None, :], axis=-1), 0.0)
     # invalid persons sit at OUT for good (cbar row is -inf, never bid)
@@ -435,7 +426,11 @@ def solve_collapsed_batch(cbar, keep1, keep2, price0,
     zero = jnp.zeros(p2o.shape[:-1] + (1,), jnp.int32)
 
     def stale(price, o2p):
-        return _any(keep2 & (o2p < 0) & (price > 0.0))
+        return jnp.any(keep2 & (o2p < 0) & (price > 0.0), axis=-1,
+                       keepdims=True)
+
+    def any_free(p2o):
+        return jnp.any(p2o == -1, axis=-1, keepdims=True)
 
     def run_scale(sc, carry):
         price, pi, p2o, o2p, rounds, sel, any_conv, late_conv = carry
@@ -456,12 +451,12 @@ def solve_collapsed_batch(cbar, keep1, keep2, price0,
 
         def live(s):
             price, pi, p2o, o2p, prev, it, stalled = s
-            return ((_any(p2o == -1) | stale(price, o2p))
+            return ((any_free(p2o) | stale(price, o2p))
                     & (it < max_rounds) & (stalled == 0))
 
         def body(s):
             price, pi, p2o, o2p, prev, it, _ = s
-            free_any = _any(p2o == -1)
+            free_any = any_free(p2o)
             if rev_every > 0:
                 periodic = (it % rev_every) == (rev_every - 1)
             else:
@@ -478,10 +473,10 @@ def solve_collapsed_batch(cbar, keep1, keep2, price0,
             # (rev_every) is ping-ponging a contested object ±ε per phase
             # — neither can ever make further progress
             p_price, p_pi, p_p2o = prev
-            same1 = (_all(price2 == price) & _all(pi2 == pi)
-                     & _all(p2o2 == p2o))
-            same2 = (_all(price2 == p_price) & _all(pi2 == p_pi)
-                     & _all(p2o2 == p_p2o))
+            same1 = jnp.all((price2 == price) & (pi2 == pi) & (p2o2 == p2o),
+                            axis=-1, keepdims=True)
+            same2 = jnp.all((price2 == p_price) & (pi2 == p_pi)
+                            & (p2o2 == p_p2o), axis=-1, keepdims=True)
             new = (price2, pi2, p2o2, o2p2, (price, pi, p2o), it + 1,
                    (same1 | same2).astype(jnp.int32))
             on = live(s)
@@ -492,7 +487,7 @@ def solve_collapsed_batch(cbar, keep1, keep2, price0,
         price, pi, p2o, o2p, _, it, _ = lax.while_loop(
             lambda s: jnp.any(live(s)), body,
             (price, pi, p2o, o2p, prev0, zero, zero))
-        conv = ~_any(p2o == -1) & ~stale(price, o2p)
+        conv = ~any_free(p2o) & ~stale(price, o2p)
         # the finest converged scale's assignment so far, and whether one
         # of the two finest rungs converged (carried, not stacked: Mosaic
         # lowers only scans without per-step inputs or outputs)
