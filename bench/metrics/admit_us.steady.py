@@ -1,0 +1,2 @@
+"""Per-layer metric `admit_us.steady`; see bench/readers.py."""
+from bench.readers import admit_us as read  # noqa: F401
