@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from collections import OrderedDict
 from functools import partial
 from typing import Any, Callable, Optional
@@ -296,17 +295,39 @@ def _pad_diagram_rows(d: Diagrams, s: int) -> Diagrams:
 
 
 def _pipeline(g: GraphBatch, key: TopoPlanKey) -> Diagrams:
-    """The one reduce->persist body every single-phase execution compiles."""
-    gr = run_reduction(g, key.passes, key.dim, key.sublevel, key.fixpoint)
-    return persistence_diagrams_batched(
-        gr, max_dim=key.dim, edge_cap=key.edge_cap, tri_cap=key.tri_cap,
-        quad_cap=key.quad_cap, sublevel=key.sublevel, reducer=key.reducer,
-    )
+    """The one reduce->persist body every single-phase execution compiles.
+
+    The two phases carry the names of the two-phase path's host spans
+    (``plan.reduce``, ``plan.persist``) as named scopes, so each device op's
+    ``op_name`` metadata, and with it the profiler trace, says which phase
+    it belongs to."""
+    with jax.named_scope("plan.reduce"):
+        gr = run_reduction(g, key.passes, key.dim, key.sublevel,
+                           key.fixpoint)
+    with jax.named_scope("plan.persist"):
+        return persistence_diagrams_batched(
+            gr, max_dim=key.dim, edge_cap=key.edge_cap,
+            tri_cap=key.tri_cap, quad_cap=key.quad_cap,
+            sublevel=key.sublevel, reducer=key.reducer,
+        )
+
+
+def _plan_name(key: TopoPlanKey, kind: str = "plan") -> str:
+    """Stable name of a plan's jitted program (``jit_<name>`` is its HLO
+    module in a profiler trace), e.g. ``topo_plan_prunit_e64_t96_d1``."""
+    method = key.method.replace("+", "_")
+    return (f"topo_{kind}_{method}_e{key.edge_cap}_t{key.tri_cap}"
+            f"_d{key.dim}")
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def _build_executor(key: TopoPlanKey) -> Callable[[GraphBatch], Diagrams]:
     if key.mesh is None:
-        return jax.jit(partial(_pipeline, key=key))
+        return jax.jit(_named(lambda g: _pipeline(g, key), _plan_name(key)))
 
     # shard_map pins the whole pipeline per-device (zero collectives — under
     # plain pjit GSPMD cannot partition the vmapped scatter/gather/top-k ops
@@ -345,11 +366,13 @@ def _build_reduce_executor(key: TopoPlanKey) -> Callable:
     count_tris = key.dim >= 1 and key.tri_cap > 0
 
     def reduce_phase(g: GraphBatch):
-        gr = run_reduction(g, key.passes, key.dim, key.sublevel, key.fixpoint)
-        gc, _ = compact_batch(gr)
-        return gc, measure_counts(gc, count_triangles=count_tris)
+        with jax.named_scope("plan.reduce"):
+            gr = run_reduction(g, key.passes, key.dim, key.sublevel,
+                               key.fixpoint)
+            gc, _ = compact_batch(gr)
+            return gc, measure_counts(gc, count_triangles=count_tris)
 
-    return jax.jit(reduce_phase)
+    return jax.jit(_named(reduce_phase, _plan_name(key, "reduce")))
 
 
 _PLAN_CACHE: "OrderedDict[TopoPlanKey, TopoPlan]" = OrderedDict()
@@ -361,9 +384,6 @@ _PLAN_CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
 # clear_plan_cache alongside _PLAN_CACHE_STATS so the two never drift)
 _OBS_PC_EVENTS = obs.counter(
     "plancache.events", help="TopoPlan cache hits/misses/evictions")
-_OBS_PC_BUILD = obs.histogram(
-    "plancache.build_seconds", help="TopoPlan executor build time (host-side "
-    "trace/compile setup on a cache miss)")
 
 
 def make_topo_plan(
@@ -424,14 +444,12 @@ def make_topo_plan(
             return plan
         _PLAN_CACHE_STATS["misses"] += 1
         _OBS_PC_EVENTS.inc(event="miss")
-        t0 = time.perf_counter()
         with obs.span("plan.build", repack=repack):
             if repack == "on":
                 plan = TopoPlan(key=key,
                                 reduce_executor=_build_reduce_executor(key))
             else:
                 plan = TopoPlan(key=key, executor=_build_executor(key))
-        _OBS_PC_BUILD.observe(time.perf_counter() - t0)
         _PLAN_CACHE[key] = plan
         while len(_PLAN_CACHE) > _PLAN_CACHE_MAXSIZE:
             _PLAN_CACHE.popitem(last=False)
@@ -454,7 +472,6 @@ def clear_plan_cache() -> None:
         for k in _PLAN_CACHE_STATS:
             _PLAN_CACHE_STATS[k] = 0
         _OBS_PC_EVENTS.clear()
-        _OBS_PC_BUILD.clear()
 
 
 def topological_signature(

@@ -46,7 +46,6 @@ from typing import Iterable, Optional
 
 from .metrics import (
     Counter,
-    DEFAULT_RATIO_BUCKETS,
     DEFAULT_TIME_BUCKETS,
     Gauge,
     Histogram,
@@ -91,7 +90,7 @@ from .slo import (
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span",
-    "DEFAULT_TIME_BUCKETS", "DEFAULT_RATIO_BUCKETS",
+    "DEFAULT_TIME_BUCKETS",
     "bucket_quantile", "bucket_count_over",
     "default_registry", "next_instance",
     "counter", "gauge", "histogram", "get_instrument",

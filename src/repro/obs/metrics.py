@@ -32,8 +32,6 @@ DEFAULT_TIME_BUCKETS = (
     1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2,
     0.1, 0.3, 1.0, 3.0, 10.0, 30.0,
 )
-# default buckets for unit-interval ratios (batch occupancy, skip rates)
-DEFAULT_RATIO_BUCKETS = (0.1, 0.25, 0.5, 0.75, 0.9, 1.0)
 
 LabelKey = tuple[tuple[str, str], ...]
 

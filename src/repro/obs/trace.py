@@ -16,9 +16,12 @@ span stack.  Every completed span also feeds the ``obs.span_seconds``
 duration histogram in the metrics registry, so traces and metrics never
 disagree about where time went.
 
-``span(..., jax_profiler=True)`` additionally brackets the block with
-``jax.profiler.start_trace/stop_trace`` for XLA-level deep dives; the
-profile lands under the configured ``jax_trace_dir``.
+While enabled, each span is also a ``jax.profiler.TraceAnnotation`` of the
+same name carrying the attributes it was opened with: inside a profiler
+session (``jax.profiler.start_trace`` or ``trace``) it lands on the
+``/host:CPU`` plane of the ``.xplane.pb``, on the clock of the device ops,
+so a device idle gap can be named by the span the serving thread was in.
+Outside a session the annotation records nothing.
 """
 from __future__ import annotations
 
@@ -34,14 +37,12 @@ from .metrics import DEFAULT_TIME_BUCKETS, default_registry
 
 
 class _Config:
-    __slots__ = ("enabled", "capacity", "jax_trace_dir")
+    __slots__ = ("enabled", "capacity")
 
     def __init__(self):
         self.enabled = os.environ.get("REPRO_OBS", "").strip() not in (
             "", "0", "false", "off")
         self.capacity = 200_000
-        self.jax_trace_dir = os.environ.get(
-            "REPRO_OBS_JAX_DIR", "results/jax_trace")
 
 
 _CONFIG = _Config()
@@ -62,8 +63,7 @@ _SPAN_SECONDS = default_registry().histogram(
 
 
 def configure(enabled: Optional[bool] = None,
-              capacity: Optional[int] = None,
-              jax_trace_dir: Optional[str] = None) -> None:
+              capacity: Optional[int] = None) -> None:
     """Flip tracing on/off and tune the event buffer.
 
     Metrics instruments are unaffected — they are always live.  Only
@@ -75,8 +75,6 @@ def configure(enabled: Optional[bool] = None,
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         _CONFIG.capacity = int(capacity)
-    if jax_trace_dir is not None:
-        _CONFIG.jax_trace_dir = jax_trace_dir
 
 
 def enabled() -> bool:
@@ -111,15 +109,14 @@ _NOOP = _NoopSpan()
 class Span:
     """Live span; created by :func:`span` only while tracing is enabled."""
 
-    __slots__ = ("name", "attrs", "parent", "_t0", "_jax", "_jax_active")
+    __slots__ = ("name", "attrs", "parent", "_t0", "_annotation")
 
-    def __init__(self, name: str, attrs: dict, jax_profiler: bool):
+    def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
         self.parent: Optional[str] = None
         self._t0 = 0.0
-        self._jax = jax_profiler
-        self._jax_active = False
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes from inside the block (end-of-span facts like
@@ -132,25 +129,19 @@ class Span:
         if st:
             self.parent = st[-1].name
         st.append(self)
-        if self._jax:
-            try:
-                import jax
-                os.makedirs(_CONFIG.jax_trace_dir, exist_ok=True)
-                jax.profiler.start_trace(_CONFIG.jax_trace_dir)
-                self._jax_active = True
-            except Exception:
-                self._jax_active = False
+        from jax.profiler import TraceAnnotation
+
+        # opened before the clock is read and closed after it, so the
+        # span's own duration leaves the annotation's cost out
+        self._annotation = TraceAnnotation(self.name,
+                                           **_scalars(self.attrs))
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
-        if self._jax_active:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        self._annotation.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -160,8 +151,7 @@ class Span:
             args["parent"] = self.parent
         if exc_type is not None:
             args["error"] = exc_type.__name__
-        for k, v in self.attrs.items():
-            args[k] = v if isinstance(v, (int, float, bool, str)) else str(v)
+        args.update(_scalars(self.attrs))
         event = {
             "name": self.name,
             "cat": self.name.split(".", 1)[0],
@@ -188,7 +178,12 @@ class Span:
         return False
 
 
-def span(name: str, jax_profiler: bool = False, **attrs):
+def _scalars(attrs: dict) -> dict:
+    return {k: v if isinstance(v, (int, float, bool, str)) else str(v)
+            for k, v in attrs.items()}
+
+
+def span(name: str, **attrs):
     """Open a nestable trace span; usable as a context manager.
 
     Disabled path returns a shared no-op (no allocation beyond the
@@ -201,7 +196,7 @@ def span(name: str, jax_profiler: bool = False, **attrs):
         # request-context propagation: every span opened under a
         # request_context() carries the request id
         attrs["rid"] = ctx.request_id
-    return Span(name, attrs, jax_profiler)
+    return Span(name, attrs)
 
 
 def current_span() -> Optional[Span]:

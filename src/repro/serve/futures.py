@@ -37,7 +37,8 @@ class ServeFuture:
     """
 
     __slots__ = ("_event", "_value", "_error", "_cancelled", "_state_lock",
-                 "submitted_at", "resolved_at", "request_id", "deadline")
+                 "submitted_at", "picked_at", "resolved_at", "request_id",
+                 "deadline")
 
     def __init__(self, request_id: Optional[str] = None,
                  deadline: Optional[float] = None):
@@ -47,6 +48,9 @@ class ServeFuture:
         self._cancelled = False
         self._state_lock = threading.Lock()
         self.submitted_at = time.perf_counter()
+        #: ``perf_counter`` time a drain took the request off its queue
+        #: (set by frontends that stamp it; None until then).
+        self.picked_at: Optional[float] = None
         self.resolved_at: Optional[float] = None
         #: request id minted by submit() (``obs.context``); None for
         #: futures created outside a serving frontend.

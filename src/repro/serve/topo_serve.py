@@ -56,10 +56,7 @@ _C_RUNGS = obs.counter(
     help="repack='on' graphs per (input bucket, persist rung)")
 _H_QWAIT = obs.histogram(
     "serve.queue_wait_seconds",
-    help="submit -> drain-pickup wait per request")
-_H_OCC = obs.histogram(
-    "serve.batch_occupancy", help="executed batch fill vs max_batch",
-    buckets=obs.DEFAULT_RATIO_BUCKETS)
+    help="submit -> drain-pickup wait per request (picked_at - submitted_at)")
 
 # TopoWatch instruments: request outcomes + loop liveness.  The latency
 # histogram feeds the per-bucket p50/p99 SLOs (obs/slo.py); the heartbeat
@@ -412,11 +409,12 @@ class TopoServe:
                         items = [q.popleft()
                                  for _ in range(min(len(q),
                                                     self.config.max_batch))]
+                        queued = len(q)
                     if items:
                         progressed = True
                         items = self._sweep(b, items)
                     if items:
-                        served += self._execute(b, items)
+                        served += self._execute(b, items, queued)
                 if not progressed:
                     sp.set(served=served)
                     return served
@@ -448,25 +446,28 @@ class TopoServe:
             live.append((req, fut))
         return live
 
-    def _execute(self, bucket: Bucket, items: list) -> int:
+    def _execute(self, bucket: Bucket, items: list, queued: int) -> int:
+        """Run one batch and resolve its futures; ``queued`` is how many
+        requests the bucket's queue still held after the batch was cut."""
         inst = self._obs_instance
         lbl = self._bucket_label[bucket]
         reqs = tuple(r for (r, _) in items)
         futs = [f for (_, f) in items]
         now = time.perf_counter()
         for f in futs:
-            _H_QWAIT.observe(now - f.submitted_at, instance=inst)
-        _H_OCC.observe(len(items) / self.config.max_batch,
-                       instance=inst, bucket=lbl)
+            f.picked_at = now
+            _H_QWAIT.observe(f.picked_at - f.submitted_at, instance=inst)
         repack_info = None
         with obs.span("serve.batch", frontend="topo", bucket=lbl,
-                      graphs=len(items)):
+                      graphs=len(items), queued=queued):
             try:
                 with obs.span("serve.gather", bucket=lbl):
                     g = pack_requests(reqs, bucket)
                     n_pad_rows = (-len(reqs)) % self._pad_batch_to
                     if n_pad_rows:
-                        g = _pad_batch(g, n_pad_rows)
+                        with obs.span("serve.pad", bucket=lbl,
+                                      rows=n_pad_rows):
+                            g = _pad_batch(g, n_pad_rows)
                 plan = self.plan_for(bucket)
                 if self.config.repack == "on":
                     # two-phase drain: reduce → measure → repack → persist;

@@ -215,6 +215,41 @@ def test_span_feeds_duration_histogram(traced):
     assert h.series()[key].count == before.get(key, 0) + 1
 
 
+def _host_events(trace_dir, name):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return [{k: str(v) for k, v in ev.stats}
+            for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"
+            for ln in p.lines for ev in ln.events if ev.name == name]
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_lands_on_the_profiler_host_plane(tmp_path, enabled):
+    """While tracing is on, a span is a profiler annotation with its
+    attributes; off, the profiler sees nothing of it."""
+    import jax
+
+    obs.configure(enabled=enabled)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.span("serve.batch", bucket="n16", queued=3):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs.configure(enabled=False)
+        obs.clear_trace()
+    got = _host_events(tmp_path, "serve.batch")
+    assert got == ([{"bucket": "n16", "queued": "3"}] if enabled else [])
+
+
 def test_trace_capacity_drops_not_grows(traced):
     obs.configure(capacity=5)
     try:

@@ -239,3 +239,63 @@ def test_failed_batch_resolves_futures_with_error():
     assert fut.done()
     with pytest.raises(ValueError):
         fut.result(timeout=1)
+
+
+# ------------------------------------------------------------ observability
+
+def test_futures_stamp_pickup_between_submit_and_resolve():
+    srv = TopoServe(TopoServeConfig(method="prunit", max_batch=2))
+    graphs = [nx.cycle_graph(5), nx.petersen_graph(), nx.path_graph(4),
+              nx.gnp_random_graph(20, 0.2, seed=1), nx.complete_graph(6)]
+    futs = [srv.submit(*_graph_query(g)) for g in graphs]
+    assert all(f.picked_at is None for f in futs)
+    assert srv.drain() == len(graphs)
+    for f in futs:
+        f.result()
+        assert f.submitted_at <= f.picked_at <= f.resolved_at
+    # batches of one bucket are cut in submission order
+    small = [f for f in futs if f.bucket.n_pad == 16]
+    assert [f.picked_at for f in small] == sorted(f.picked_at for f in small)
+
+
+@pytest.mark.parametrize("repack", ["off", "on"])
+def test_bucket_plan_names_its_program_and_phases(repack):
+    from repro.core.graph import GraphBatch
+
+    plan = make_topo_plan(dim=1, method="prunit", edge_cap=64, tri_cap=96,
+                          repack=repack)
+    g = GraphBatch(adj=jax.ShapeDtypeStruct((4, 16, 16), bool),
+                   mask=jax.ShapeDtypeStruct((4, 16), bool),
+                   f=jax.ShapeDtypeStruct((4, 16), "float32"))
+    if repack == "off":
+        text = plan.executor.lower(g).compile().as_text()
+        assert text.startswith("HloModule jit_topo_plan_prunit_e64_t96_d1,")
+        assert "/plan.persist/" in text
+    else:
+        text = plan.reduce_plan.lower(g).compile().as_text()
+        assert text.startswith("HloModule jit_topo_reduce_prunit_e64_t96_d1,")
+        assert "/plan.persist/" not in text
+    assert "/plan.reduce/" in text
+
+
+def test_served_diagrams_match_reference():
+    import numpy as np
+
+    from repro.core.persistence_jax import diagrams_to_numpy
+    from repro.core.persistence_ref import diagrams_equal, persistence_diagrams
+
+    srv = TopoServe(TopoServeConfig(method="prunit", pad_batch_to=4))
+    graphs = [nx.cycle_graph(6), nx.petersen_graph(),
+              nx.barabasi_albert_graph(12, 2, seed=3), nx.complete_graph(7),
+              nx.gnp_random_graph(40, 0.1, seed=2)]
+    queries = [_graph_query(g) for g in graphs]
+    futs = [srv.submit(e, n) for e, n in queries]
+    srv.drain()
+    for (edges, n), fut in zip(queries, futs):
+        adj = np.zeros((n, n), bool)
+        for u, v in edges:
+            adj[u, v] = adj[v, u] = True
+        ref = persistence_diagrams(adj, adj.sum(1).astype(float), max_dim=1)
+        got = diagrams_to_numpy(jax.tree.map(lambda x: x[None], fut.result()),
+                                0, 1)
+        assert diagrams_equal(ref, got), (ref, got)
