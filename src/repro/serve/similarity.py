@@ -108,13 +108,13 @@ _BUCKET = "query"
 @dataclasses.dataclass(frozen=True)
 class SimilarityResult:
     """kNN answer for one query graph: parallel id/distance lists plus the
-    query's own Diagrams slice (so clients can inspect or re-index it).
+    query's own Diagrams row (so clients can inspect or re-index it).
     ``backends[i]`` names the metric backend that produced ``distances[i]``
     (``"gram"`` embedding-L1, or ``"exact_w"`` after the re-rank stage)."""
 
     ids: tuple[str, ...]
     distances: tuple[float, ...]
-    diagrams: object  # per-graph Diagrams slice (leaves shaped (S,))
+    diagrams: object  # the query's Diagrams row (host leaves shaped (S,))
     backends: tuple[str, ...] = ()
 
 
@@ -147,14 +147,15 @@ def _stack_by_shape(rows):
 
     Rows resolved in one drain can come from different padding buckets and
     therefore carry different tensor sizes S; the embedding is S-independent
-    but ``jnp.stack`` is not, so batching happens per shape class.  Yields
+    but stacking is not, so batching happens per shape class.  The rows are
+    host arrays, stacked on the host so each leaf uploads once.  Yields
     ``(original_indices, stacked_batch)``.
     """
     groups: dict[tuple, list[int]] = {}
     for i, r in enumerate(rows):
         groups.setdefault(tuple(r.birth.shape), []).append(i)
     for idxs in groups.values():
-        batch = jax.tree.map(lambda *xs: jax.numpy.stack(xs),
+        batch = jax.tree.map(lambda *xs: np.stack(xs),
                              *[rows[i] for i in idxs])
         yield idxs, batch
 

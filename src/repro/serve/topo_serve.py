@@ -10,8 +10,8 @@ ROADMAP's "serve heavy traffic" direction; docs/ARCHITECTURE.md §TopoServe):
   signatures is bounded by the bucket ladder, not by the query distribution;
 * ``drain()`` packs each bucket's queue into a padded GraphBatch, executes
   the bucket's plan through the process-wide plan cache
-  (``repro.core.api.make_topo_plan``), and resolves the futures with
-  per-graph Diagrams slices.
+  (``repro.core.api.make_topo_plan``), fetches the batch's Diagrams to the
+  host in one transfer, and resolves each future with its graph's row.
 
 The loop is deliberately sync-first (``submit``/``drain`` under one lock) so
 it is trivially testable; ``serve_forever`` runs the same drain as a blocking
@@ -47,6 +47,9 @@ from repro.serve.futures import ServeFuture
 _C_SUBMITTED = obs.counter("serve.submitted",
                            help="requests accepted per bucket")
 _C_SERVED = obs.counter("serve.served", help="futures resolved per bucket")
+_C_RESOLVE_BYTES = obs.counter(
+    "serve.resolve_bytes",
+    help="device->host bytes of executed batches' diagrams per bucket")
 _C_FAILED = obs.counter("serve.failed", help="futures failed at drain")
 _C_BATCHES = obs.counter("serve.batches", help="executed batches per bucket")
 _C_PADDED = obs.counter("serve.padded_rows",
@@ -138,10 +141,11 @@ class TopoRequest:
 class TopoFuture(ServeFuture):
     """Handle for one submitted graph; resolved by a later ``drain()``.
 
-    ``result()`` returns the per-graph Diagrams slice (leaves shaped (S,),
-    no batch axis).  Thread-safe plumbing — including ``cancel()`` and the
-    request id / deadline carried from ``submit()`` — lives in
-    ``ServeFuture``.  With ``repack="on"``, ``repack_class`` carries the
+    ``result()`` returns the graph's Diagrams row on the host: NumPy leaves
+    shaped (S,), no batch axis, each a copy of its own (the drain fetches
+    the whole batch in one device->host transfer).  Thread-safe plumbing —
+    including ``cancel()`` and the request id / deadline carried from
+    ``submit()`` — lives in ``ServeFuture``.  With ``repack="on"``, ``repack_class`` carries the
     persist :class:`ShapeClass` this request was re-bucketed into (set at
     drain, before the future resolves).
     """
@@ -487,13 +491,21 @@ class TopoServe:
                 return 0
             if self.config.record_batches:
                 self.executed_batches.append((bucket, reqs, tuple(futs)))
-            with obs.span("serve.resolve"):
+            with obs.span("serve.resolve") as sp:
+                # one device->host transfer per leaf for the whole batch
+                # (pad rows included), then a host-owned copy of each row
+                # so a client holding one answer does not pin the batch
+                host = jax.device_get(d)
+                n_bytes = sum(x.nbytes for x in jax.tree.leaves(host))
+                sp.set(bytes=n_bytes)
                 for i, f in enumerate(futs):
                     if repack_info is not None:
                         f.repack_class = repack_info.shape_class(i)
-                    if f._resolve(jax.tree.map(lambda x: x[i], d)):
+                    if f._resolve(jax.tree.map(lambda x: np.array(x[i]),
+                                               host)):
                         _H_LATENCY.observe(f.latency_s(),
                                            instance=inst, bucket=lbl)
+        _C_RESOLVE_BYTES.inc(n_bytes, instance=inst, bucket=lbl)
         _C_SERVED.inc(len(futs), instance=inst, bucket=lbl)
         _C_BATCHES.inc(instance=inst, bucket=lbl)
         _flight.record("serve", "batch", frontend="topo", bucket=lbl,

@@ -3,6 +3,7 @@ import threading
 
 import jax
 import networkx as nx
+import numpy as np
 import pytest
 
 from repro.core import topological_signature
@@ -82,6 +83,13 @@ def test_serve_reuses_plans_across_drains():
 
 # -------------------------------------------------------------------- serve
 
+def _assert_host_row(d):
+    """A served answer: NumPy leaves of shape (S,), each owning its memory
+    (a client holding one answer pins no other row of its batch)."""
+    for x in jax.tree.leaves(d):
+        assert type(x) is np.ndarray and x.ndim == 1 and x.base is None
+
+
 def test_served_equals_direct_single_bucket():
     srv = TopoServe(TopoServeConfig(method="prunit", record_batches=True))
     graphs = [nx.cycle_graph(6), nx.petersen_graph(),
@@ -95,6 +103,7 @@ def test_served_equals_direct_single_bucket():
         edge_cap=bucket.edge_cap, tri_cap=bucket.tri_cap,
     )
     for i, fut in enumerate(bfuts):
+        _assert_host_row(fut.result())
         assert _rows_equal(fut.result(), jax.tree.map(lambda x: x[i], direct))
 
 
@@ -117,7 +126,88 @@ def test_served_equals_direct_across_buckets_and_padding():
             edge_cap=bucket.edge_cap, tri_cap=bucket.tri_cap,
         )
         for i, fut in enumerate(bfuts):
+            _assert_host_row(fut.result())
             assert _rows_equal(fut.result(), jax.tree.map(lambda x: x[i], direct))
+
+
+_PARITY_GRAPHS = [nx.cycle_graph(6), nx.petersen_graph(),
+                  nx.barabasi_albert_graph(12, 2, seed=3), nx.path_graph(4)]
+
+
+@pytest.mark.parametrize("n_graphs,repack", [
+    (3, "off"),   # partly filled: one pad row executed, never handed out
+    (4, "off"),   # full batch
+    (3, "on"),    # two-phase plan, pad row included
+])
+def test_served_rows_are_host_rows_of_plan_execute(n_graphs, repack):
+    from repro.serve.topo_serve import _pad_batch
+
+    srv = TopoServe(TopoServeConfig(method="prunit", max_batch=4,
+                                    pad_batch_to=4, repack=repack,
+                                    record_batches=True))
+    graphs = _PARITY_GRAPHS[:n_graphs]
+    futs = [srv.submit(*_graph_query(g)) for g in graphs]
+    assert srv.drain() == n_graphs
+    assert srv.stats["padded_rows"] == 4 - n_graphs
+    (bucket, reqs, bfuts), = srv.executed_batches
+    assert list(bfuts) == futs
+    g = pack_requests(reqs, bucket)
+    if n_graphs < 4:
+        g = _pad_batch(g, 4 - n_graphs)
+    plan = srv.plan_for(bucket)
+    direct, info = plan.execute_info(g)
+    for i, fut in enumerate(futs):
+        _assert_host_row(fut.result())
+        assert _rows_equal(fut.result(), jax.tree.map(lambda x: x[i], direct))
+        if repack == "on":
+            assert fut.repack_class == info.shape_class(i)
+        else:
+            assert fut.repack_class is None
+
+
+def test_batch_resolves_with_one_transfer_and_no_per_graph_programs(
+        monkeypatch):
+    from repro import obs
+
+    array_type = type(jax.numpy.zeros(()))
+
+    srv = TopoServe(TopoServeConfig(method="prunit", pad_batch_to=4))
+    q = _graph_query(nx.cycle_graph(6))
+    srv.submit(*q)
+    srv.drain()  # compile the bucket plan and the pad outside the count
+
+    gets, slices = [], []
+    real_get, real_item = jax.device_get, array_type.__getitem__
+
+    def device_get(x):
+        out = real_get(x)
+        gets.append(sum(a.nbytes for a in jax.tree.leaves(out)))
+        return out
+
+    def getitem(self, idx):
+        slices.append(idx)
+        return real_item(self, idx)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(array_type, "__getitem__", getitem)
+    before = obs.get_instrument("serve.resolve_bytes").value(
+        instance=srv._obs_instance, bucket="n16")
+    futs = [srv.submit(*_graph_query(g)) for g in _PARITY_GRAPHS[:3]]
+    obs.configure(enabled=True)
+    obs.clear_trace()
+    try:
+        assert srv.drain() == 3
+        spans = [e for e in obs.trace_events() if e["name"] == "serve.resolve"]
+    finally:
+        obs.configure(enabled=False)
+        obs.clear_trace()
+    assert len(gets) == 1 and slices == []
+    assert [e["args"]["bytes"] for e in spans] == gets
+    after = obs.get_instrument("serve.resolve_bytes").value(
+        instance=srv._obs_instance, bucket="n16")
+    # the whole padded batch moved: 4 rows of each leaf, 3 handed out
+    row_bytes = sum(x.nbytes for x in jax.tree.leaves(futs[0].result()))
+    assert after - before == gets[0] == 4 * row_bytes
 
 
 def test_served_diagram_values():
@@ -198,7 +288,6 @@ def test_mesh_pad_rounds_up_to_mesh_multiple():
 
 def test_signature_features_matches_feature_vector():
     from repro.topo.features import feature_vector, signature_features
-    import numpy as np
 
     plan = make_topo_plan(dim=1, method="prunit", edge_cap=64, tri_cap=96)
     g = pack_requests(
@@ -279,8 +368,6 @@ def test_bucket_plan_names_its_program_and_phases(repack):
 
 
 def test_served_diagrams_match_reference():
-    import numpy as np
-
     from repro.core.persistence_jax import diagrams_to_numpy
     from repro.core.persistence_ref import diagrams_equal, persistence_diagrams
 
