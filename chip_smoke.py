@@ -218,13 +218,13 @@ def phase_topo_single(traffic, watch):
     from repro.core.api import topological_signature
     from repro.core.persistence_jax import diagrams_bitwise_equal
     from repro.core.persistence_ref import diagrams_equal, persistence_diagrams
-    from repro.serve import TopoServe, TopoServeConfig
-    from repro.serve.topo_serve import _pad_batch, pack_requests
+    from repro.serve import SINGLE_PHASE_BUCKETS, TopoServe, TopoServeConfig
+    from repro.serve.topo_serve import pack_requests
     import jax
 
     mark = watch.mark()
-    cfg = TopoServeConfig(max_batch=BATCH, pad_batch_to=BATCH,
-                          record_batches=True)
+    cfg = TopoServeConfig(buckets=SINGLE_PHASE_BUCKETS, max_batch=BATCH,
+                          pad_batch_to=BATCH, record_batches=True)
     srv = TopoServe(cfg)
     t0 = time.perf_counter()
     futs, results = serve_all(srv, traffic)
@@ -234,9 +234,7 @@ def phase_topo_single(traffic, watch):
     for bucket, reqs, bfuts in srv.executed_batches:
         if len(reqs) == BATCH:
             full[bucket] = full.get(bucket, 0) + 1
-        g = pack_requests(reqs, bucket)
-        if len(reqs) < BATCH:
-            g = _pad_batch(g, BATCH - len(reqs))
+        g = pack_requests(reqs, bucket, BATCH)
         direct = topological_signature(
             g, dim=cfg.dim, method=cfg.method, sublevel=cfg.sublevel,
             edge_cap=bucket.edge_cap, tri_cap=bucket.tri_cap,
@@ -295,11 +293,11 @@ def phase_topo_pallas(traffic, single, watch):
         reduce_packed,
     )
     from repro.kernels import ops
-    from repro.serve import TopoServe, TopoServeConfig
-    from repro.serve.topo_serve import DEFAULT_BUCKETS, pack_requests
+    from repro.serve import SINGLE_PHASE_BUCKETS, TopoServe, TopoServeConfig
+    from repro.serve.topo_serve import pack_requests
 
     mark = watch.mark()
-    top = DEFAULT_BUCKETS[-1]
+    top = SINGLE_PHASE_BUCKETS[-1]
     idx = [i for i, r in enumerate(traffic) if r["bucket"] == top][:BATCH]
     reqs = [traffic[i] for i in idx]
     require(len(reqs) == BATCH, f"top bucket holds {len(reqs)} graphs")
@@ -341,10 +339,11 @@ def phase_topo_pallas(traffic, single, watch):
 
 
 def phase_topo_repack(traffic, single, watch):
-    from repro.serve import TopoServe, TopoServeConfig
+    from repro.serve import SINGLE_PHASE_BUCKETS, TopoServe, TopoServeConfig
 
     mark = watch.mark()
-    srv = TopoServe(TopoServeConfig(max_batch=BATCH, pad_batch_to=BATCH,
+    srv = TopoServe(TopoServeConfig(buckets=SINGLE_PHASE_BUCKETS,
+                                    max_batch=BATCH, pad_batch_to=BATCH,
                                     repack="on"))
     exact_dim = srv.plan_for(srv.config.buckets[0]).exact_from_dim()
     dims = list(range(exact_dim, srv.config.dim + 1))
@@ -431,14 +430,16 @@ def check_neighbors(emb_q, base, ids, dists, want_ids, what):
 
 def phase_similarity(seed: int, traffic, watch):
     from repro.index import TopoIndexConfig
-    from repro.serve import SimilarityServe, TopoServeConfig
+    from repro.serve import (SINGLE_PHASE_BUCKETS, SimilarityServe,
+                             TopoServeConfig)
     from repro.serve.similarity import _stack_by_shape
 
     mark = watch.mark()
     base, _ = build_corpus(seed, TopoIndexConfig(coarse="lsh"))
     sim = SimilarityServe(
         index=base, sharded=True, default_k=K,
-        config=TopoServeConfig(max_batch=BATCH, pad_batch_to=BATCH))
+        config=TopoServeConfig(buckets=SINGLE_PHASE_BUCKETS,
+                               max_batch=BATCH, pad_batch_to=BATCH))
     rng = np.random.default_rng(seed + 11)
     pick = rng.permutation(len(traffic))
     adds, queries = pick[:N_QUERIES], pick[N_QUERIES:2 * N_QUERIES]
@@ -480,11 +481,11 @@ def phase_four_chips(seed: int, traffic, watch):
 
     from repro.index import ShardedIndex, TopoIndexConfig
     from repro.launch.mesh import make_index_mesh, make_serve_mesh
-    from repro.serve import TopoServe, TopoServeConfig
-    from repro.serve.topo_serve import DEFAULT_BUCKETS, pack_requests
+    from repro.serve import SINGLE_PHASE_BUCKETS, TopoServe, TopoServeConfig
+    from repro.serve.topo_serve import pack_requests
 
     mark = watch.mark()
-    top = DEFAULT_BUCKETS[-1]
+    top = SINGLE_PHASE_BUCKETS[-1]
     reqs = [r for r in traffic if r["bucket"] == top]
     # the one-device reference runs the batch each chip of the mesh runs
     # (BATCH / 4): the same per-graph program, a quarter of the compile
@@ -558,10 +559,10 @@ def main() -> int:
     log("device", platform=platform, kind=devices[0].device_kind,
         count=len(devices), jax=jax.__version__, compile_cache=cache_dir)
 
-    from repro.serve import TopoServe, TopoServeConfig
+    from repro.serve import SINGLE_PHASE_BUCKETS, TopoServe, TopoServeConfig
 
     t0 = time.perf_counter()
-    router = TopoServe(TopoServeConfig())
+    router = TopoServe(TopoServeConfig(buckets=SINGLE_PHASE_BUCKETS))
     traffic, over_top = ego_traffic(args.seed, router)
     n_ego = len(traffic)
     top_up(traffic, router, args.seed)

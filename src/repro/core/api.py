@@ -57,6 +57,7 @@ from repro.core.repack import (
     compact_batch,
     default_ladder,
     diagram_size,
+    holds_every_graph,
     measure_counts,
     select_classes,
     slice_to,
@@ -125,7 +126,9 @@ class TopoPlan:
     carries a mesh) program; with ``repack="on"`` it is the two-phase driver
     — ``reduce_plan`` (jitted) → host repack → per-rung ``persist_plan``
     execution — and ``execute_info`` additionally returns the
-    :class:`~repro.core.repack.RepackReport` of rung assignments.  The plan
+    :class:`~repro.core.repack.RepackReport` of rung assignments.
+    ``executor`` is the program a batch enters first: the whole pipeline
+    single-phase, the reduce plan two-phase.  The plan
     object is safe to hold across requests — re-executing with the same
     (B, N) shape never recompiles.
     """
@@ -135,10 +138,16 @@ class TopoPlan:
     reduce_executor: Optional[Callable] = None
     _ladders: dict = dataclasses.field(
         default_factory=dict, compare=False, repr=False)
+    _warmed: set = dataclasses.field(
+        default_factory=set, compare=False, repr=False)
 
     def execute(self, g: GraphBatch) -> Diagrams:
         if self.key.repack == "on":
-            return self.execute_info(g)[0]
+            d, info = self.execute_info(g)
+            bad = np.flatnonzero(info.class_index < 0)
+            if bad.size:
+                raise info.misfit(int(bad[0]))
+            return d
         # dispatch is async: this span covers trace/dispatch, not device
         # time — callers that block (serve) wrap the sync in serve.sync
         with obs.span("plan.execute", graphs=g.batch, n=g.n):
@@ -217,13 +226,21 @@ class TopoPlan:
                         and c.tri_cap <= k.tri_cap
                         and (c.quad_cap == k.quad_cap if quads_live
                              else c.quad_cap <= k.quad_cap))}
-            fits.add(top)
+            if not self.order_only(n):
+                fits.add(top)
             lad = tuple(sorted(fits))
         else:
             lad = default_ladder(
                 n, k.edge_cap, k.tri_cap if k.dim >= 1 else 0,
                 k.quad_cap if k.dim >= 2 else 0)
         return self._ladders.setdefault(n, lad)
+
+    def order_only(self, n: int) -> bool:
+        """Whether this two-phase plan's caps hold every graph of padded
+        order ``n``: it then persists only at the rungs of its ladder."""
+        k = self.key
+        return (k.repack == "on" and k.ladder is not None
+                and holds_every_graph(n, k.edge_cap, k.tri_cap))
 
     def execute_info(self, g: GraphBatch
                      ) -> tuple[Diagrams, Optional[RepackReport]]:
@@ -232,11 +249,17 @@ class TopoPlan:
         Two-phase driver: reduce/compact/measure under one jitted program,
         fetch the per-graph counts to the host (the one phase-boundary
         sync), group graphs by first-fit shape class, run each group —
-        padded to a power-of-two batch so jit signatures stay bounded —
-        through its rung's persist plan, and scatter the rows back into an
-        input-order Diagrams tensor padded to the single-phase row count
-        (rows past a rung's capacity are invalid padding, so downstream
-        masked arithmetic and canonical-pair extraction see one shape).
+        padded to 8, 32, 128, ... rows and at most the batch, so jit
+        signatures stay few — through its rung's persist plan, and
+        scatter the rows into an input-order Diagrams tensor as wide as
+        the ladder's widest rung (rows past a rung's capacity are invalid
+        padding, so downstream masked arithmetic and canonical-pair
+        extraction see one shape).  A graph reduced to no vertex has an
+        empty diagram and runs no persist program; a graph that fits no
+        rung keeps an invalid row and index -1 in the report
+        (``RepackReport.misfit``).  An order-only plan compiles, on its
+        first batch of a shape, every persist program that shape can form,
+        so no later batch compiles.
         """
         if self.key.repack != "on":
             return self.executor(g), None
@@ -248,36 +271,76 @@ class TopoPlan:
         with obs.span("plan.repack"):
             ladder = self.ladder_for(g.n)
             cls_idx = select_classes(ladder, nv, ne, nt)
-        s_full = diagram_size(g.n, k.dim, k.edge_cap, k.tri_cap, k.quad_cap)
-        out = _invalid_diagrams(g.batch, s_full)
-        for ci in sorted(set(cls_idx.tolist())):
+        s_out = max((diagram_size(sc.n_pad, k.dim, sc.edge_cap, sc.tri_cap,
+                                  sc.quad_cap) for sc in ladder), default=0)
+        out = _invalid_diagrams(g.batch, s_out)
+        if self.order_only(g.n) and (g.batch, g.n) not in self._warmed:
+            with obs.span("plan.warm", graphs=g.batch, n=g.n):
+                for sc in ladder:
+                    for r in _group_sizes(g.batch):
+                        self._persist_into(out, gc, np.zeros(r, np.int32),
+                                           sc)
+            self._warmed.add((g.batch, g.n))
+        live = (cls_idx >= 0) & (nv > 0)
+        calls = 0
+        for ci in sorted(set(cls_idx[live].tolist())):
             sc = ladder[ci]
-            idx = np.nonzero(cls_idx == ci)[0]
-            n_g = len(idx)
-            r = 1 << (n_g - 1).bit_length()  # pow2-padded group batch
-            with obs.span("plan.persist", rung=f"n{sc.n_pad}", graphs=n_g):
+            idx = np.nonzero(live & (cls_idx == ci))[0].astype(np.int32)
+            r = _group_rows(len(idx), g.batch)
+            with obs.span("plan.persist", rung=f"n{sc.n_pad}",
+                          graphs=len(idx)):
                 idx_p = np.concatenate(
-                    [idx, np.full(r - n_g, idx[0], idx.dtype)])
-                jidx = jnp.asarray(idx_p)
-                sub = slice_to(jax.tree.map(lambda x: x[jidx], gc), sc.n_pad)
-                d = self.persist_plan(sc).execute(sub)
-                d = _pad_diagram_rows(d, s_full)
-                jdst = jnp.asarray(idx)
-                out = jax.tree.map(
-                    lambda o, n_: o.at[jdst].set(n_[:n_g]), out, d)
+                    [idx, np.full(r - len(idx), idx[0], np.int32)])
+                out = self._persist_into(out, gc, idx_p, sc)
+            calls += 1
         report = RepackReport(ladder=ladder, class_index=cls_idx,
-                              n_vertices=nv, n_edges=ne, n_triangles=nt)
+                              n_vertices=nv, n_edges=ne, n_triangles=nt,
+                              persist_calls=calls)
         return out, report
+
+    def _persist_into(self, out: Diagrams, gc: GraphBatch, idx: np.ndarray,
+                      sc: ShapeClass) -> Diagrams:
+        """Persist compacted graphs ``idx`` at rung ``sc`` and write their
+        rows into ``out``.  Padding entries repeat a real index, so their
+        rows are that graph's and the scatter stays exact."""
+        sub = _take_rows(gc, idx, n_pad=sc.n_pad)
+        return _put_rows(out, self.persist_plan(sc).execute(sub), idx)
+
+
+def _group_rows(n: int, batch: int) -> int:
+    """Rows a persist group of ``n`` graphs runs at: 8, 32, 128, ...,
+    at most the batch."""
+    r = 8
+    while r < n:
+        r *= 4
+    return min(r, batch)
+
+
+def _group_sizes(batch: int) -> list[int]:
+    """Every row count ``_group_rows`` gives for a batch of ``batch``."""
+    return sorted({_group_rows(n, batch) for n in range(1, batch + 1)})
+
+
+@partial(jax.jit, static_argnames=("n_pad",))
+def _take_rows(g: GraphBatch, idx: jax.Array, n_pad: int) -> GraphBatch:
+    return jax.tree.map(lambda x: x[idx], slice_to(g, n_pad))
+
+
+@jax.jit
+def _put_rows(out: Diagrams, d: Diagrams, idx: jax.Array) -> Diagrams:
+    d = _pad_diagram_rows(d, out.birth.shape[-1])
+    return jax.tree.map(lambda o, x: o.at[idx].set(x), out, d)
 
 
 def _invalid_diagrams(b: int, s: int) -> Diagrams:
-    """An all-invalid Diagrams tensor matching pairs_to_diagrams sentinels."""
-    return Diagrams(
-        birth=jnp.full((b, s), jnp.nan, jnp.float32),
-        death=jnp.full((b, s), jnp.nan, jnp.float32),
-        dim=jnp.full((b, s), -1, jnp.int32),
-        valid=jnp.zeros((b, s), bool),
-    )
+    """An all-invalid Diagrams tensor matching pairs_to_diagrams sentinels,
+    built on the host and uploaded (no device program to compile)."""
+    return jax.device_put(Diagrams(
+        birth=np.full((b, s), np.nan, np.float32),
+        death=np.full((b, s), np.nan, np.float32),
+        dim=np.full((b, s), -1, np.int32),
+        valid=np.zeros((b, s), bool),
+    ))
 
 
 def _pad_diagram_rows(d: Diagrams, s: int) -> Diagrams:
@@ -446,8 +509,9 @@ def make_topo_plan(
         _OBS_PC_EVENTS.inc(event="miss")
         with obs.span("plan.build", repack=repack):
             if repack == "on":
-                plan = TopoPlan(key=key,
-                                reduce_executor=_build_reduce_executor(key))
+                reduce_exec = _build_reduce_executor(key)
+                plan = TopoPlan(key=key, executor=reduce_exec,
+                                reduce_executor=reduce_exec)
             else:
                 plan = TopoPlan(key=key, executor=_build_executor(key))
         _PLAN_CACHE[key] = plan

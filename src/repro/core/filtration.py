@@ -168,7 +168,10 @@ def build_filtered_complex(
     s_total = values.shape[0]
 
     # --- filtration order: (value, dim, slot) lexicographic ---
-    perm = jnp.lexsort((jnp.arange(s_total), dims, values))
+    # slots are laid out by dimension (vertices, edges, triangles, tetra),
+    # so a stable sort by value alone gives that order; one key compiles
+    # several times faster than three at a few thousand slots
+    perm = jnp.argsort(values, stable=True)
     pos_of_slot = jnp.zeros(s_total, jnp.int32).at[perm].set(
         jnp.arange(s_total, dtype=jnp.int32)
     )

@@ -63,6 +63,15 @@ def diagram_size(n: int, dim: int, edge_cap: int, tri_cap: int,
     return s
 
 
+def holds_every_graph(n: int, edge_cap: int, tri_cap: int) -> bool:
+    """Whether caps at padded order ``n`` hold every graph of that order:
+    the complete graph's edges and triangles.  Such a shape is an
+    admission class, never a persist shape: its diagram would have on the
+    order of ``n**3 / 6`` rows."""
+    return (edge_cap >= n * (n - 1) // 2
+            and tri_cap >= n * (n - 1) * (n - 2) // 6)
+
+
 def compact_batch(g: GraphBatch) -> tuple[GraphBatch, jax.Array]:
     """Permute surviving vertices to the front of the padded axis.
 
@@ -147,9 +156,10 @@ def select_classes(ladder: tuple[ShapeClass, ...], nv, ne, nt) -> np.ndarray:
     """First-fit rung index per graph (host-side, vectorized).
 
     A graph lands on the first rung holding all its measured counts —
-    deterministic, like TopoServe's bucket routing.  Raises if some graph
-    fits no rung (impossible for ``default_ladder``; a custom ladder must
-    keep a top rung at least as large as the input shape).
+    deterministic, like TopoServe's bucket routing.  A graph that fits no
+    rung (impossible for ``default_ladder``; a custom ladder must keep a
+    top rung at least as large as the input shape) gets index -1;
+    ``RepackReport.misfit`` names its counts.
     """
     nv = np.asarray(nv)
     ne = np.asarray(ne)
@@ -162,11 +172,6 @@ def select_classes(ladder: tuple[ShapeClass, ...], nv, ne, nt) -> np.ndarray:
         fit = ((out < 0) & (nv <= c.n_pad) & (ne <= c.edge_cap)
                & (nt <= c.tri_cap))
         out[fit] = i
-    if (out < 0).any():
-        bad = np.nonzero(out < 0)[0].tolist()
-        raise ValueError(
-            f"graphs {bad} fit no repack shape class (ladder top rung "
-            f"{ladder[-1]}); custom ladders must cover the input shape")
     return out
 
 
@@ -185,14 +190,29 @@ class RepackReport:
     n_vertices: np.ndarray    # (B,) post-reduction counts
     n_edges: np.ndarray
     n_triangles: np.ndarray
+    persist_calls: int = 0    # persist programs the execution ran
 
-    def shape_class(self, i: int) -> ShapeClass:
-        return self.ladder[int(self.class_index[i])]
+    def shape_class(self, i: int) -> ShapeClass | None:
+        """Graph ``i``'s persist rung, or None where it fits none."""
+        ci = int(self.class_index[i])
+        return self.ladder[ci] if ci >= 0 else None
+
+    def misfit(self, i: int) -> ValueError | None:
+        """The error of graph ``i`` if its reduced counts fit no rung."""
+        if self.class_index[i] >= 0:
+            return None
+        return ValueError(
+            f"graph {i} reduced to {int(self.n_vertices[i])} vertices / "
+            f"{int(self.n_edges[i])} edges / {int(self.n_triangles[i])} "
+            f"triangles, which fit no persist rung (largest "
+            f"{max(self.ladder, default=None)})")
 
     def rung_histogram(self) -> dict[int, int]:
-        """{rung n_pad: graph count} over the batch."""
+        """{rung n_pad: graph count} over the graphs that fit a rung."""
         out: dict[int, int] = {}
         for ci in self.class_index.tolist():
+            if ci < 0:
+                continue
             n = self.ladder[ci].n_pad
             out[n] = out.get(n, 0) + 1
         return out

@@ -11,6 +11,7 @@ from repro.serve.similarity import (
 from repro.serve.stream_serve import StreamFuture, StreamServe
 from repro.serve.topo_serve import (
     DEFAULT_BUCKETS,
+    SINGLE_PHASE_BUCKETS,
     Bucket,
     TopoFuture,
     TopoRequest,
@@ -22,6 +23,7 @@ from repro.serve.topo_serve import (
 __all__ = [
     "Bucket",
     "DEFAULT_BUCKETS",
+    "SINGLE_PHASE_BUCKETS",
     "SimilarityFuture",
     "SimilarityResult",
     "SimilarityServe",

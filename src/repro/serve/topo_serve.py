@@ -13,6 +13,14 @@ ROADMAP's "serve heavy traffic" direction; docs/ARCHITECTURE.md §TopoServe):
   (``repro.core.api.make_topo_plan``), fetches the batch's Diagrams to the
   host in one transfer, and resolves each future with its graph's row.
 
+A bucket whose caps hold every graph of its order is **order-only**: it
+admits by order any graph that fits no single-phase bucket, and the server
+serves it reduce-first (reduce → measure → repack → persist at the sparse
+rungs of ``order_only_ladder_for``), whatever ``repack`` says.  A graph is
+answered only where what it reduces to fits one of those rungs; one that
+does not fails its own future.  Where the method reduces nothing, that is
+known at ``submit``, which rejects the graph there.
+
 The loop is deliberately sync-first (``submit``/``drain`` under one lock) so
 it is trivially testable; ``serve_forever`` runs the same drain as a blocking
 loop for a dedicated thread, and ``serve_forever_async`` wraps it for an
@@ -37,7 +45,9 @@ from repro.obs.context import DeadlineExceeded, resolve_submit
 from repro.core.api import TopoPlan, make_topo_plan
 from repro.core.graph import GraphBatch, from_edge_lists
 from repro.core.persistence_jax import Diagrams
-from repro.core.repack import ShapeClass, default_ladder
+from repro.core.reduction import passes_for_method
+from repro.core.repack import (ShapeClass, default_ladder, holds_every_graph,
+                               select_classes)
 from repro.serve.futures import ServeFuture
 
 # TopoScope instruments (always on; one series per server instance via the
@@ -56,7 +66,19 @@ _C_PADDED = obs.counter("serve.padded_rows",
                         help="empty pad rows executed (mesh divisibility)")
 _C_RUNGS = obs.counter(
     "serve.repack_rungs",
-    help="repack='on' graphs per (input bucket, persist rung)")
+    help="two-phase graphs per (input bucket, persist rung)")
+_C_REDUCED_V = obs.counter(
+    "serve.reduced_vertices",
+    help="post-reduction vertices of two-phase requests per bucket")
+_C_REDUCED_E = obs.counter(
+    "serve.reduced_edges",
+    help="post-reduction edges of two-phase requests per bucket")
+_C_REDUCED_T = obs.counter(
+    "serve.reduced_triangles",
+    help="post-reduction triangles of two-phase requests per bucket")
+_C_PERSIST_CALLS = obs.counter(
+    "serve.persist_calls",
+    help="persist programs run by two-phase batches per bucket")
 _H_QWAIT = obs.histogram(
     "serve.queue_wait_seconds",
     help="submit -> drain-pickup wait per request (picked_at - submitted_at)")
@@ -93,26 +115,55 @@ class Bucket:
     edge_cap: int
     tri_cap: int
 
+    @property
+    def order_only(self) -> bool:
+        """Caps that hold every graph of this order: the bucket admits by
+        order alone and is served reduce-first, persisting only what fits
+        the sparse rungs of ``order_only_ladder_for``."""
+        return holds_every_graph(self.n_pad, self.edge_cap, self.tri_cap)
 
-# Default ladder: ego-net-regime graphs (the paper's §6.2 workload).  Caps
-# grow with the vertex budget; a graph lands in the first rung that fits
-# both its order and its edge count.
-DEFAULT_BUCKETS = (
+
+def order_only_bucket(n_pad: int) -> Bucket:
+    """The order-only bucket admitting every graph of at most ``n_pad``
+    vertices."""
+    return Bucket(n_pad=n_pad, edge_cap=n_pad * (n_pad - 1) // 2,
+                  tri_cap=n_pad * (n_pad - 1) * (n_pad - 2) // 6)
+
+
+# Single-phase rungs: ego-net-regime graphs (the paper's §6.2 workload).
+# Caps grow with the vertex budget; a graph lands in the first rung that
+# fits its order, its edge count and its triangle count.
+SINGLE_PHASE_BUCKETS = (
     Bucket(n_pad=16, edge_cap=64, tri_cap=96),
     Bucket(n_pad=32, edge_cap=160, tri_cap=256),
     Bucket(n_pad=64, edge_cap=320, tri_cap=512),
     Bucket(n_pad=128, edge_cap=768, tri_cap=1024),
 )
+# Above them, order-only rungs for graphs of up to 2,048 vertices (the
+# graph-classification corpora, REDDIT-BINARY's threads among them).
+DEFAULT_BUCKETS = SINGLE_PHASE_BUCKETS + tuple(
+    order_only_bucket(n) for n in (256, 512, 1024, 2048))
+
+# Order-only buckets persist what they reduce to at sparse rungs: orders
+# doubling from 8 to half the largest order-only order, with at most 2
+# edges a vertex and 64 triangles.  No bound on a reduction gives these
+# caps (a cone reduces to one vertex, a cocktail-party graph not at all):
+# they are fitted to the post-reduction counts of generated
+# REDDIT-BINARY-shaped threads (PERF.md §4): at most 0.44 of the input
+# order, 1.51 edges a vertex and 10 triangles.
+PERSIST_EDGES_PER_VERTEX = 2
+PERSIST_TRI_CAP = 64
 
 
 @dataclasses.dataclass(frozen=True)
 class TopoServeConfig:
     """Scheduler policy + the pipeline parameters shared by every bucket.
 
-    ``repack="on"`` switches every bucket to the two-phase plan: drain
-    becomes reduce → measure → repack → persist, where the persist phase
-    runs at each graph's post-reduction :class:`ShapeClass` instead of the
-    input bucket's caps.  The persist ladder is shared across buckets (see
+    Order-only buckets always take the two-phase plan.  ``repack="on"``
+    switches the other buckets to it as well: drain becomes reduce →
+    measure → repack → persist, where the persist phase runs at each
+    graph's post-reduction :class:`ShapeClass` instead of the input
+    bucket's caps.  The persist ladder is shared across buckets (see
     ``repack_ladder_for``), so reduced graphs from different input buckets
     execute the same compiled persist plans.
     """
@@ -145,9 +196,9 @@ class TopoFuture(ServeFuture):
     shaped (S,), no batch axis, each a copy of its own (the drain fetches
     the whole batch in one device->host transfer).  Thread-safe plumbing —
     including ``cancel()`` and the request id / deadline carried from
-    ``submit()`` — lives in ``ServeFuture``.  With ``repack="on"``, ``repack_class`` carries the
-    persist :class:`ShapeClass` this request was re-bucketed into (set at
-    drain, before the future resolves).
+    ``submit()`` — lives in ``ServeFuture``.  On the two-phase path
+    ``repack_class`` carries the persist :class:`ShapeClass` this request
+    was re-bucketed into (set at drain, before the future resolves).
     """
 
     __slots__ = ("bucket", "repack_class")
@@ -159,9 +210,18 @@ class TopoFuture(ServeFuture):
         self.repack_class: ShapeClass | None = None
 
 
-def pack_requests(reqs: Sequence[TopoRequest], bucket: Bucket) -> GraphBatch:
+# a pad row: the empty graph (no live vertex, +inf filtration)
+_EMPTY_REQUEST = TopoRequest(edges=(), n_vertices=0)
+
+
+def pack_requests(reqs: Sequence[TopoRequest], bucket: Bucket,
+                  rows: int | None = None) -> GraphBatch:
     """Pad a bucket's requests into one GraphBatch (shared with benchmarks
-    so served-vs-direct parity checks run the exact same packing)."""
+    so served-vs-direct parity checks run the exact same packing), with
+    empty graphs after them up to ``rows`` rows.  Packed on the host and
+    uploaded once: padding compiles no program of its own."""
+    reqs = tuple(reqs)
+    reqs += (_EMPTY_REQUEST,) * ((rows or len(reqs)) - len(reqs))
     if all(r.f is None for r in reqs):
         f_values = None  # from_edge_lists' vectorized degree-filtration default
     else:
@@ -190,29 +250,54 @@ def repack_ladder_for(buckets: Sequence[Bucket],
                       quad_cap: int = 0) -> tuple[ShapeClass, ...]:
     """The ONE persist-shape ladder shared by every repack-enabled server.
 
-    Rungs are the serve buckets themselves (so a reduced graph that stays
-    large persists at a familiar bucket shape) plus the default pow2
-    sub-rungs below the smallest bucket (where most reduced ego-regime
-    graphs land).  TopoServe and SimilarityServe both derive their ladders
-    here — one definition, one set of persist plan-cache keys, so reduced
-    queries from any serving surface share compiled persist pipelines.
+    Rungs are the single-phase buckets themselves (so a reduced graph that
+    stays large persists at a familiar bucket shape) plus the default pow2
+    sub-rungs below the smallest of them (where most reduced ego-regime
+    graphs land); order-only buckets are never rungs.  TopoServe and
+    SimilarityServe both derive their ladders here — one definition, one
+    set of persist plan-cache keys, so reduced queries from any serving
+    surface share compiled persist pipelines.
     """
-    smallest = min(buckets)
+    single = sorted(b for b in buckets if not b.order_only)
+    if not single:
+        return ()
+    smallest = single[0]
     sub = default_ladder(smallest.n_pad, smallest.edge_cap,
                          smallest.tri_cap, quad_cap)[:-1]
     classes = {ShapeClass(n_pad=b.n_pad, edge_cap=b.edge_cap,
                           tri_cap=b.tri_cap, quad_cap=quad_cap)
-               for b in buckets}
+               for b in single}
     classes.update(sub)
     return tuple(sorted(classes))
 
 
-def _count_triangles(edge_set, n_vertices: int) -> int:
-    """Host-side triangle count (trace(A^3)/6) for cap-aware routing."""
-    a = np.zeros((n_vertices, n_vertices), dtype=np.int64)
+def order_only_ladder_for(buckets: Sequence[Bucket],
+                          quad_cap: int = 0) -> tuple[ShapeClass, ...]:
+    """The persist ladder of the order-only buckets: sparse rungs of order
+    8, 16, ... up to half the largest order-only order, each holding
+    ``PERSIST_EDGES_PER_VERTEX`` edges a vertex and ``PERSIST_TRI_CAP``
+    triangles (or the complete graph's, where fewer)."""
+    top = max((b.n_pad for b in buckets if b.order_only), default=0)
+    rungs = []
+    n = 8
+    while n <= top // 2:
+        rungs.append(ShapeClass(
+            n_pad=n,
+            edge_cap=min(PERSIST_EDGES_PER_VERTEX * n, n * (n - 1) // 2),
+            tri_cap=min(PERSIST_TRI_CAP, n * (n - 1) * (n - 2) // 6),
+            quad_cap=quad_cap))
+        n *= 2
+    return tuple(rungs)
+
+
+def _count_triangles(edge_set) -> int:
+    """Host-side triangle count for cap-aware routing: the common
+    neighbours of each edge's ends, each triangle seen from its 3 edges."""
+    nbrs: dict[int, set] = {}
     for (u, v) in edge_set:
-        a[u, v] = a[v, u] = 1
-    return int(np.trace(a @ a @ a) // 6)
+        nbrs.setdefault(u, set()).add(v)
+        nbrs.setdefault(v, set()).add(u)
+    return sum(len(nbrs[u] & nbrs[v]) for (u, v) in edge_set) // 3
 
 
 class TopoServe:
@@ -239,9 +324,13 @@ class TopoServe:
                 "phase boundary is host-driven); use repack='off'")
         self.mesh = mesh
         self._buckets = tuple(sorted(self.config.buckets))
+        self._single = tuple(b for b in self._buckets if not b.order_only)
+        self._order_only = tuple(b for b in self._buckets if b.order_only)
         self._repack_ladder = (
             repack_ladder_for(self._buckets, self.config.quad_cap)
             if self.config.repack == "on" else None)
+        self._order_only_ladder = order_only_ladder_for(
+            self._buckets, self.config.quad_cap)
         pad = max(1, self.config.pad_batch_to)
         if mesh is not None:
             # executed batches must DIVIDE the mesh (shard_map contract), so
@@ -297,7 +386,7 @@ class TopoServe:
             "cancelled": int(_C_CANCELLED.total(instance=inst)),
             "batches": sum(pb["batches"] for pb in per_bucket.values()),
             "padded_rows": int(_C_PADDED.value(instance=inst)),
-            # repack="on": {(bucket n_pad, persist rung n_pad): graphs} —
+            # two-phase: {(bucket n_pad, persist rung n_pad): graphs} —
             # rungs keyed by >1 bucket are shared compiled persist plans
             "repack_rungs": rungs,
             "per_bucket": per_bucket,
@@ -307,14 +396,19 @@ class TopoServe:
 
     def bucket_for(self, n_vertices: int, n_edges: int,
                    n_triangles: int = 0) -> Bucket:
-        """Deterministic bucket assignment: the smallest rung (buckets are
-        totally ordered by (n_pad, edge_cap, tri_cap)) whose capacities hold
-        every simplex of the graph — exactness requires caps >= the true
-        counts (docs/ARCHITECTURE.md §GraphBatch invariants), so a
-        triangle-dense graph is promoted past rungs its edge count fits."""
-        for b in self._buckets:
+        """Deterministic bucket assignment: the smallest single-phase rung
+        (buckets are totally ordered by (n_pad, edge_cap, tri_cap)) whose
+        capacities hold every simplex of the graph — exactness requires
+        caps >= the true counts (docs/ARCHITECTURE.md §GraphBatch
+        invariants), so a triangle-dense graph is promoted past rungs its
+        edge count fits — else the smallest order-only rung that holds its
+        order."""
+        for b in self._single:
             if (n_vertices <= b.n_pad and n_edges <= b.edge_cap
                     and n_triangles <= b.tri_cap):
+                return b
+        for b in self._order_only:
+            if n_vertices <= b.n_pad:
                 return b
         raise ValueError(
             f"graph with {n_vertices} vertices / {n_edges} edges / "
@@ -324,16 +418,24 @@ class TopoServe:
     def plan_for(self, bucket: Bucket) -> TopoPlan:
         """The bucket's compiled pipeline, via the process-wide plan cache.
 
-        With ``repack="on"`` every bucket's plan shares the one serve-wide
-        persist ladder, so their reduced-size persist plans coincide in the
-        plan cache whenever reductions land on the same rung.
+        Order-only buckets get a two-phase plan on the order-only ladder,
+        and with ``repack="on"`` every other bucket one on the serve-wide
+        persist ladder; either way the buckets' reduced-size persist plans
+        coincide in the plan cache whenever reductions land on the same
+        rung.  The two-phase plan runs on one device: its phase boundary is
+        host-driven.
         """
         c = self.config
+        if bucket.order_only:
+            ladder = self._order_only_ladder
+        else:
+            ladder = self._repack_ladder
         return make_topo_plan(
             dim=c.dim, method=c.method, sublevel=c.sublevel,
             edge_cap=bucket.edge_cap, tri_cap=bucket.tri_cap,
-            quad_cap=c.quad_cap, reducer=c.reducer, mesh=self.mesh,
-            repack=c.repack, ladder=self._repack_ladder,
+            quad_cap=c.quad_cap, reducer=c.reducer,
+            mesh=None if ladder is not None else self.mesh,
+            repack="off" if ladder is None else "on", ladder=ladder,
         )
 
     # ------------------------------------------------------------- ingest
@@ -371,8 +473,25 @@ class TopoServe:
             raise ValueError(
                 f"f has {len(req.f)} values for {req.n_vertices} vertices")
         edge_set = {(min(u, v), max(u, v)) for (u, v) in req.edges if u != v}
-        bucket = self.bucket_for(req.n_vertices, len(edge_set),
-                                 _count_triangles(edge_set, req.n_vertices))
+        # triangles matter only where a single-phase rung holds the order
+        # and the edges; any other graph is routed by its order alone
+        n_tri = (_count_triangles(edge_set)
+                 if any(req.n_vertices <= b.n_pad
+                        and len(edge_set) <= b.edge_cap for b in self._single)
+                 else 0)
+        bucket = self.bucket_for(req.n_vertices, len(edge_set), n_tri)
+        if bucket.order_only and not passes_for_method(self.config.method):
+            # nothing reduces, so the graph persists as it came: admit it
+            # only where a rung of the persist ladder holds it
+            n_tri = _count_triangles(edge_set)
+            if select_classes(self._order_only_ladder, [req.n_vertices],
+                              [len(edge_set)], [n_tri])[0] < 0:
+                raise ValueError(
+                    f"graph with {req.n_vertices} vertices / "
+                    f"{len(edge_set)} edges / {n_tri} triangles fits no "
+                    f"persist rung (largest "
+                    f"{max(self._order_only_ladder, default=None)}) and "
+                    f"method={self.config.method!r} reduces nothing")
         rid, deadline = resolve_submit(request_id, deadline_s)
         fut = TopoFuture(bucket, request_id=rid, deadline=deadline)
         with self._lock:
@@ -451,8 +570,9 @@ class TopoServe:
         return live
 
     def _execute(self, bucket: Bucket, items: list, queued: int) -> int:
-        """Run one batch and resolve its futures; ``queued`` is how many
-        requests the bucket's queue still held after the batch was cut."""
+        """Run one batch and resolve its futures; returns how many it
+        answered.  ``queued`` is how many requests the bucket's queue still
+        held after the batch was cut."""
         inst = self._obs_instance
         lbl = self._bucket_label[bucket]
         reqs = tuple(r for (r, _) in items)
@@ -465,15 +585,11 @@ class TopoServe:
         with obs.span("serve.batch", frontend="topo", bucket=lbl,
                       graphs=len(items), queued=queued):
             try:
-                with obs.span("serve.gather", bucket=lbl):
-                    g = pack_requests(reqs, bucket)
-                    n_pad_rows = (-len(reqs)) % self._pad_batch_to
-                    if n_pad_rows:
-                        with obs.span("serve.pad", bucket=lbl,
-                                      rows=n_pad_rows):
-                            g = _pad_batch(g, n_pad_rows)
+                n_pad_rows = (-len(reqs)) % self._pad_batch_to
+                with obs.span("serve.gather", bucket=lbl, pad=n_pad_rows):
+                    g = pack_requests(reqs, bucket, len(reqs) + n_pad_rows)
                 plan = self.plan_for(bucket)
-                if self.config.repack == "on":
+                if plan.key.repack == "on":
                     # two-phase drain: reduce → measure → repack → persist;
                     # the report carries each request's persist-rung
                     # assignment (plan.* spans nest here)
@@ -491,6 +607,7 @@ class TopoServe:
                 return 0
             if self.config.record_batches:
                 self.executed_batches.append((bucket, reqs, tuple(futs)))
+            served = n_failed = 0
             with obs.span("serve.resolve") as sp:
                 # one device->host transfer per leaf for the whole batch
                 # (pad rows included), then a host-owned copy of each row
@@ -499,25 +616,44 @@ class TopoServe:
                 n_bytes = sum(x.nbytes for x in jax.tree.leaves(host))
                 sp.set(bytes=n_bytes)
                 for i, f in enumerate(futs):
+                    err = None
                     if repack_info is not None:
                         f.repack_class = repack_info.shape_class(i)
+                        err = repack_info.misfit(i)
+                    if err is not None:
+                        # a graph no persist rung holds fails alone
+                        n_failed += f._fail(err)
+                        continue
+                    served += 1
                     if f._resolve(jax.tree.map(lambda x: np.array(x[i]),
                                                host)):
                         _H_LATENCY.observe(f.latency_s(),
                                            instance=inst, bucket=lbl)
         _C_RESOLVE_BYTES.inc(n_bytes, instance=inst, bucket=lbl)
-        _C_SERVED.inc(len(futs), instance=inst, bucket=lbl)
+        _C_SERVED.inc(served, instance=inst, bucket=lbl)
         _C_BATCHES.inc(instance=inst, bucket=lbl)
         _flight.record("serve", "batch", frontend="topo", bucket=lbl,
                        graphs=len(futs))
+        if n_failed:
+            _C_FAILED.inc(n_failed, instance=inst)
         if n_pad_rows:
             _C_PADDED.inc(n_pad_rows, instance=inst)
         if repack_info is not None:
-            for i in range(len(futs)):
-                _C_RUNGS.inc(
-                    instance=inst, bucket=f"n{bucket.n_pad}",
-                    rung=f"n{repack_info.shape_class(i).n_pad}")
-        return len(futs)
+            k = len(futs)
+            _C_REDUCED_V.inc(int(repack_info.n_vertices[:k].sum()),
+                             instance=inst, bucket=lbl)
+            _C_REDUCED_E.inc(int(repack_info.n_edges[:k].sum()),
+                             instance=inst, bucket=lbl)
+            _C_REDUCED_T.inc(int(repack_info.n_triangles[:k].sum()),
+                             instance=inst, bucket=lbl)
+            _C_PERSIST_CALLS.inc(repack_info.persist_calls,
+                                 instance=inst, bucket=lbl)
+            for i in range(k):
+                sc = repack_info.shape_class(i)
+                if sc is not None:
+                    _C_RUNGS.inc(instance=inst, bucket=f"n{bucket.n_pad}",
+                                 rung=f"n{sc.n_pad}")
+        return served
 
     # ------------------------------------------------------------- loops
 
@@ -593,14 +729,3 @@ class TopoServe:
     def stop(self) -> None:
         self._stopped.set()
 
-
-def _pad_batch(g: GraphBatch, n_rows: int) -> GraphBatch:
-    """Append ``n_rows`` empty graphs (all-padding rows) to a batch."""
-    import jax.numpy as jnp
-
-    def pad(x, fill):
-        pad_shape = (n_rows,) + x.shape[1:]
-        return jnp.concatenate([x, jnp.full(pad_shape, fill, x.dtype)], axis=0)
-
-    return GraphBatch(adj=pad(g.adj, False), mask=pad(g.mask, False),
-                      f=pad(g.f, jnp.inf))

@@ -9,8 +9,16 @@ import pytest
 from repro.core import topological_signature
 from repro.core.api import clear_plan_cache, make_topo_plan, plan_cache_info
 from repro.core.persistence_jax import diagrams_bitwise_equal as _rows_equal
+from repro.core.repack import ShapeClass, diagram_size
 from repro.serve import Bucket, TopoServe, TopoServeConfig
-from repro.serve.topo_serve import pack_requests
+from repro.serve.topo_serve import (
+    DEFAULT_BUCKETS,
+    SINGLE_PHASE_BUCKETS,
+    order_only_bucket,
+    order_only_ladder_for,
+    pack_requests,
+    repack_ladder_for,
+)
 
 
 def _graph_query(g: nx.Graph):
@@ -140,7 +148,7 @@ _PARITY_GRAPHS = [nx.cycle_graph(6), nx.petersen_graph(),
     (3, "on"),    # two-phase plan, pad row included
 ])
 def test_served_rows_are_host_rows_of_plan_execute(n_graphs, repack):
-    from repro.serve.topo_serve import _pad_batch
+    from repro.core.graph import GraphBatch
 
     srv = TopoServe(TopoServeConfig(method="prunit", max_batch=4,
                                     pad_batch_to=4, repack=repack,
@@ -152,8 +160,14 @@ def test_served_rows_are_host_rows_of_plan_execute(n_graphs, repack):
     (bucket, reqs, bfuts), = srv.executed_batches
     assert list(bfuts) == futs
     g = pack_requests(reqs, bucket)
-    if n_graphs < 4:
-        g = _pad_batch(g, 4 - n_graphs)
+    k = 4 - n_graphs   # pad rows: empty graphs, +inf filtration
+    g = GraphBatch(
+        adj=np.concatenate([g.adj, np.zeros((k,) + g.adj.shape[1:], bool)]),
+        mask=np.concatenate([g.mask, np.zeros((k, g.n), bool)]),
+        f=np.concatenate([g.f, np.full((k, g.n), np.inf, np.float32)]))
+    padded = pack_requests(reqs, bucket, 4)
+    for a, b in zip(jax.tree.leaves(padded), jax.tree.leaves(g)):
+        assert np.array_equal(np.asarray(a), b)
     plan = srv.plan_for(bucket)
     direct, info = plan.execute_info(g)
     for i, fut in enumerate(futs):
@@ -237,9 +251,10 @@ def test_background_serve_forever_thread():
 
 
 def test_oversize_request_rejected_at_submit():
+    # above the largest order-only rung (2,048 vertices) no bucket holds it
     srv = TopoServe()
-    with pytest.raises(ValueError):
-        srv.submit(edges=[(i, i + 1) for i in range(200)], n_vertices=201)
+    with pytest.raises(ValueError, match="exceeds every bucket"):
+        srv.submit(edges=[(i, i + 1) for i in range(2100)], n_vertices=2101)
 
 
 def test_malformed_requests_rejected_at_submit():
@@ -386,3 +401,233 @@ def test_served_diagrams_match_reference():
         got = diagrams_to_numpy(jax.tree.map(lambda x: x[None], fut.result()),
                                 0, 1)
         assert diagrams_equal(ref, got), (ref, got)
+
+
+# ---------------------------------------------------------- order-only rungs
+
+# a small single-phase ladder with order-only rungs above it, so that
+# graphs of 9 to 64 vertices take the two-phase path; their persist ladder
+# is (8, 16, 56), (16, 32, 64), (32, 64, 64)
+_SMALL = (Bucket(8, 16, 16), order_only_bucket(16), order_only_bucket(32),
+          order_only_bucket(64))
+
+
+def _small_server(**kw):
+    return TopoServe(TopoServeConfig(buckets=_SMALL, method="both",
+                                     max_batch=4, pad_batch_to=4, **kw))
+
+
+def _thread(n: int, extra: int, seed: int) -> nx.Graph:
+    """A reply tree grown by preferential attachment, plus ``extra`` repeat
+    interactions: a discussion thread in miniature."""
+    g = nx.barabasi_albert_graph(n, 1, seed=seed)
+    rng = np.random.default_rng(seed)
+    while extra:
+        u, v = (int(x) for x in rng.integers(0, n, 2))
+        if u != v and not g.has_edge(u, v):
+            g.add_edge(u, v)
+            extra -= 1
+    return g
+
+
+def _pd1_matches_reference(fut, g: nx.Graph) -> bool:
+    """PD_1 of the served answer against the oracle on the UNREDUCED
+    graph (CoralTDA and PrunIT are exact for PD_1)."""
+    from repro.core.persistence_jax import diagrams_to_numpy
+    from repro.core.persistence_ref import diagrams_equal, persistence_diagrams
+
+    edges, n = _graph_query(g)
+    adj = np.zeros((n, n), bool)
+    for u, v in edges:
+        adj[u, v] = adj[v, u] = True
+    ref = persistence_diagrams(adj, adj.sum(1).astype(float), max_dim=1)
+    got = diagrams_to_numpy(jax.tree.map(lambda x: x[None], fut.result()),
+                            0, 1)
+    return diagrams_equal({1: ref.get(1, [])}, {1: got[1]})
+
+
+def _counter(name, srv, bucket):
+    from repro import obs
+
+    return obs.get_instrument(name).value(instance=srv._obs_instance,
+                                          bucket=bucket)
+
+
+def _cocktail_party(k: int) -> nx.Graph:
+    """K_{2,...,2}: no vertex dominates another and every vertex is in the
+    2-core, so the reductions keep it whole (2k vertices, 4k(k-1)(k-2)/3
+    triangles)."""
+    return nx.complement(nx.from_edgelist([(2 * i, 2 * i + 1)
+                                           for i in range(k)]))
+
+
+def test_default_ladder_keeps_order_only_rungs_apart():
+    single = [b for b in DEFAULT_BUCKETS if not b.order_only]
+    order_only = [b for b in DEFAULT_BUCKETS if b.order_only]
+    assert [b.n_pad for b in single] == [16, 32, 64, 128]
+    assert [b.n_pad for b in order_only] == [256, 512, 1024, 2048]
+    # one harness label per rung: no order-only n_pad repeats another's
+    assert len({b.n_pad for b in DEFAULT_BUCKETS}) == len(DEFAULT_BUCKETS)
+    # no persist ladder holds an order-only rung's caps
+    assert repack_ladder_for(DEFAULT_BUCKETS) == repack_ladder_for(
+        SINGLE_PHASE_BUCKETS)
+    ladder = order_only_ladder_for(DEFAULT_BUCKETS)
+    assert [c.n_pad for c in ladder] == [8, 16, 32, 64, 128, 256, 512, 1024]
+    assert ladder[-1] == ShapeClass(1024, 2048, 64)
+    assert not any(c in repack_ladder_for(DEFAULT_BUCKETS) for c in ladder)
+
+
+@pytest.mark.parametrize("n,ne,nt,want", [
+    (8, 16, 16, 8),      # fits the single-phase rung exactly
+    (9, 8, 0, 16),       # order over the single-phase rungs
+    (8, 17, 0, 16),      # edges over them: routed by order
+    (8, 10, 17, 16),     # triangles over them
+    (17, 20, 0, 32),
+    (32, 496, 4960, 32),  # the complete graph of the rung's order
+])
+def test_order_only_rungs_route_by_order(n, ne, nt, want):
+    assert _small_server().bucket_for(n, ne, nt).n_pad == want
+
+
+def test_order_only_rung_rejects_a_larger_order():
+    with pytest.raises(ValueError, match="exceeds every bucket"):
+        _small_server().bucket_for(65, 70, 0)
+
+
+@pytest.mark.parametrize("graph,admitted", [
+    (lambda: _thread(20, 3, 0), True),    # fits the n32 persist rung as is
+    (lambda: _thread(40, 3, 0), False),   # above the ladder's top order
+    (lambda: _cocktail_party(8), False),  # 16 vertices, 112 edges
+])
+def test_order_only_rung_without_reduction_admits_what_a_rung_holds(
+        graph, admitted):
+    """With method='none' nothing reduces, so submit knows whether a
+    persist rung will hold the graph: it rejects one that none holds and
+    serves the rest."""
+    g = graph()
+    srv = TopoServe(TopoServeConfig(buckets=_SMALL, method="none",
+                                    max_batch=4, pad_batch_to=4))
+    if not admitted:
+        with pytest.raises(ValueError, match="reduces nothing"):
+            srv.submit(*_graph_query(g))
+        assert srv.pending() == 0
+        return
+    fut = srv.submit(*_graph_query(g))
+    assert fut.bucket.order_only and srv.drain() == 1
+    assert _pd1_matches_reference(fut, g)
+
+
+def test_single_phase_rungs_keep_their_plans():
+    srv = TopoServe(TopoServeConfig(method="prunit"))
+    for b in SINGLE_PHASE_BUCKETS:
+        plan = srv.plan_for(b)
+        assert plan is make_topo_plan(dim=1, method="prunit",
+                                      edge_cap=b.edge_cap,
+                                      tri_cap=b.tri_cap)
+        assert plan.key.repack == "off" and plan.key.ladder is None
+    for b in DEFAULT_BUCKETS[len(SINGLE_PHASE_BUCKETS):]:
+        plan = srv.plan_for(b)
+        assert plan.key.repack == "on" and plan.order_only(b.n_pad)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_order_only_threads_match_the_reference(seed):
+    srv = _small_server()
+    graphs = [_thread(n, extra, seed * 10 + j) for j, (n, extra) in
+              enumerate([(6, 1), (12, 3), (14, 2), (20, 4), (26, 5),
+                         (30, 6), (31, 0)])]
+    futs = [srv.submit(*_graph_query(g)) for g in graphs]
+    assert {f.bucket.n_pad for f in futs} == {8, 16, 32}
+    assert srv.drain() == len(graphs)
+    for g, fut in zip(graphs, futs):
+        assert _pd1_matches_reference(fut, g)
+        if fut.bucket.order_only:
+            assert fut.repack_class is not None
+            assert fut.repack_class.n_pad <= fut.bucket.n_pad
+
+
+def test_order_only_batch_fails_a_misfit_alone_and_counts_the_rest():
+    srv = _small_server(record_batches=True)
+    mates = [_thread(n, 3, n) for n in (18, 24, 28)]
+    graphs = mates[:2] + [_cocktail_party(16)] + mates[2:]
+    futs = [srv.submit(*_graph_query(g)) for g in graphs]
+    assert all(f.bucket == _SMALL[2] for f in futs)
+    assert srv.drain() == 3
+    # 32 vertices, 480 edges, 4,480 triangles: no persist rung holds it
+    with pytest.raises(ValueError, match="4480 triangles"):
+        futs[2].result(timeout=1)
+    assert futs[2].repack_class is None and srv.stats["failed"] == 1
+    for g, fut in zip(mates, futs[:2] + futs[3:]):
+        assert _pd1_matches_reference(fut, g)
+    # the counters advance by the batch's post-reduction counts
+    (bucket, reqs, _), = srv.executed_batches
+    d, info = srv.plan_for(bucket).execute_info(pack_requests(reqs, bucket))
+    assert list(info.class_index < 0) == [False, False, True, False]
+    for name, want in [("reduced_vertices", info.n_vertices.sum()),
+                       ("reduced_edges", info.n_edges.sum()),
+                       ("reduced_triangles", info.n_triangles.sum()),
+                       ("persist_calls", info.persist_calls)]:
+        assert _counter(f"serve.{name}", srv, "n32") == want > 0
+    # one row width for the batch: the widest rung of its persist ladder
+    assert d.birth.shape == (4, max(diagram_size(c.n_pad, 1, c.edge_cap,
+                                                 c.tri_cap)
+                                    for c in info.ladder))
+    assert not np.asarray(d.valid)[2].any()
+    # called directly, the plan refuses the batch rather than pad it
+    with pytest.raises(ValueError, match="fit no persist rung"):
+        srv.plan_for(bucket).execute(pack_requests(reqs, bucket))
+
+
+def test_order_only_rung_compiles_nothing_after_its_first_batch():
+    import jax.monitoring as mon
+
+    compiles = []
+
+    def listen(event, duration, **_):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and listen.on):
+            compiles.append(duration)
+
+    listen.on = False
+    mon.register_event_duration_secs_listener(listen)
+    srv = _small_server()
+    seeds = iter(range(100))
+    # the first batch holds trees alone: reduced to nothing, they run no
+    # persist program, so every later one was compiled up front
+    batches = [[(20, 0), (24, 0), (30, 0), (32, 0)],
+               [(20, 2), (24, 3), (30, 5), (32, 1)],
+               [(17, 0), (28, 6), (19, 4), (25, 2)],
+               [(31, 7), (22, 1), (29, 3), (18, 5)]]
+    rungs = set()
+    for j, batch in enumerate(batches):
+        listen.on = j > 0
+        futs = [srv.submit(*_graph_query(_thread(n, e, next(seeds))))
+                for n, e in batch]
+        assert srv.drain() == 4
+        if j == 0:
+            assert _counter("serve.persist_calls", srv, "n32") == 0
+        else:
+            rungs |= {f.repack_class for f in futs}
+    assert compiles == [] and len(rungs) >= 2
+    listen.on = False
+
+
+@pytest.mark.parametrize("n,batch,rows", [
+    (1, 32, 8), (8, 32, 8), (9, 32, 32), (32, 32, 32), (3, 4, 4),
+    (33, 256, 128), (129, 256, 256)])
+def test_persist_groups_run_at_few_row_counts(n, batch, rows):
+    from repro.core.api import _group_rows, _group_sizes
+
+    assert _group_rows(n, batch) == rows
+    assert rows in _group_sizes(batch)
+    assert _group_sizes(32) == [8, 32]
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_triangle_count_matches_dense_trace(seed):
+    from repro.serve.topo_serve import _count_triangles
+
+    g = nx.gnp_random_graph(40, 0.1 + 0.1 * seed, seed=seed)
+    edge_set = {(min(u, v), max(u, v)) for (u, v) in g.edges()}
+    a = nx.to_numpy_array(g, nodelist=range(40), dtype=np.int64)
+    assert _count_triangles(edge_set) == int(np.trace(a @ a @ a)) // 6
