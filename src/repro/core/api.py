@@ -30,6 +30,8 @@ Plans execute in one of two modes (docs/ARCHITECTURE.md §ReductionEngine):
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import threading
 from collections import OrderedDict
@@ -42,7 +44,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.graph import GraphBatch
-from repro.core.persistence_jax import Diagrams, persistence_diagrams_batched
+from repro.core.persistence_jax import Diagrams, persistence_diagrams_counted
 from repro.core.reduction import (
     apply_passes,
     engine_exact_from_dim,
@@ -127,6 +129,8 @@ class TopoPlan:
     — ``reduce_plan`` (jitted) → host repack → per-rung ``persist_plan``
     execution — and ``execute_info`` additionally returns the
     :class:`~repro.core.repack.RepackReport` of rung assignments.
+    Inside ``collect_columns()`` each persist program executed also
+    reports the column steps its GF(2) loops ran.
     ``executor`` is the program a batch enters first: the whole pipeline
     single-phase, the reduce plan two-phase.  The plan
     object is safe to hold across requests — re-executing with the same
@@ -151,7 +155,11 @@ class TopoPlan:
         # dispatch is async: this span covers trace/dispatch, not device
         # time — callers that block (serve) wrap the sync in serve.sync
         with obs.span("plan.execute", graphs=g.batch, n=g.n):
-            return self.executor(g)
+            d, cols = self.executor(g)
+        sink = _COLUMNS.get()
+        if sink is not None:
+            sink.append(cols)
+        return d
 
     def __call__(self, g: GraphBatch) -> Diagrams:
         return self.execute(g)
@@ -262,7 +270,7 @@ class TopoPlan:
         so no later batch compiles.
         """
         if self.key.repack != "on":
-            return self.executor(g), None
+            return self.execute(g), None
         k = self.key
         with obs.span("plan.reduce", graphs=g.batch, n=g.n):
             gc, counts = self.reduce_executor(g)
@@ -275,7 +283,9 @@ class TopoPlan:
                                   sc.quad_cap) for sc in ladder), default=0)
         out = _invalid_diagrams(g.batch, s_out)
         if self.order_only(g.n) and (g.batch, g.n) not in self._warmed:
-            with obs.span("plan.warm", graphs=g.batch, n=g.n):
+            # set-up: its column steps are not collected
+            with obs.span("plan.warm", graphs=g.batch, n=g.n), \
+                    collect_columns():
                 for sc in ladder:
                     for r in _group_sizes(g.batch):
                         self._persist_into(out, gc, np.zeros(r, np.int32),
@@ -305,6 +315,28 @@ class TopoPlan:
         rows are that graph's and the scatter stays exact."""
         sub = _take_rows(gc, idx, n_pad=sc.n_pad)
         return _put_rows(out, self.persist_plan(sc).execute(sub), idx)
+
+
+_COLUMNS: contextvars.ContextVar[Optional[list]] = contextvars.ContextVar(
+    "topo_plan_columns", default=None)
+
+
+@contextlib.contextmanager
+def collect_columns():
+    """Collect the GF(2) column steps of the plan programs this thread
+    executes inside the block.
+
+    Yields a list that gains one device array per persist program run, (2,)
+    i32 [column steps run, column slots of the caps], or (devices, 2) under
+    a mesh (``persistence_diagrams_counted``).  The arrays stay on the
+    device, so the caller fetches them with the diagrams in one transfer.
+    """
+    sink: list = []
+    token = _COLUMNS.set(sink)
+    try:
+        yield sink
+    finally:
+        _COLUMNS.reset(token)
 
 
 def _group_rows(n: int, batch: int) -> int:
@@ -357,8 +389,10 @@ def _pad_diagram_rows(d: Diagrams, s: int) -> Diagrams:
     )
 
 
-def _pipeline(g: GraphBatch, key: TopoPlanKey) -> Diagrams:
-    """The one reduce->persist body every single-phase execution compiles.
+def _pipeline(g: GraphBatch, key: TopoPlanKey) -> tuple[Diagrams, jax.Array]:
+    """The one reduce->persist body every single-phase execution compiles;
+    returns the diagrams and the GF(2) loops' column steps
+    (``persistence_diagrams_counted``).
 
     The two phases carry the names of the two-phase path's host spans
     (``plan.reduce``, ``plan.persist``) as named scopes, so each device op's
@@ -368,7 +402,7 @@ def _pipeline(g: GraphBatch, key: TopoPlanKey) -> Diagrams:
         gr = run_reduction(g, key.passes, key.dim, key.sublevel,
                            key.fixpoint)
     with jax.named_scope("plan.persist"):
-        return persistence_diagrams_batched(
+        return persistence_diagrams_counted(
             gr, max_dim=key.dim, edge_cap=key.edge_cap,
             tri_cap=key.tri_cap, quad_cap=key.quad_cap,
             sublevel=key.sublevel, reducer=key.reducer,
@@ -388,7 +422,8 @@ def _named(fn: Callable, name: str) -> Callable:
     return fn
 
 
-def _build_executor(key: TopoPlanKey) -> Callable[[GraphBatch], Diagrams]:
+def _build_executor(key: TopoPlanKey
+                    ) -> Callable[[GraphBatch], tuple[Diagrams, jax.Array]]:
     if key.mesh is None:
         return jax.jit(_named(lambda g: _pipeline(g, key), _plan_name(key)))
 
@@ -403,16 +438,19 @@ def _build_executor(key: TopoPlanKey) -> Callable[[GraphBatch], Diagrams]:
     spec = P(tuple(mesh.axis_names))
 
     def per_device(adj, mask, f):
-        return _pipeline(GraphBatch(adj=adj, mask=mask, f=f), key)
+        # each device bounds its own loops: one column-steps row a device
+        d, cols = _pipeline(GraphBatch(adj=adj, mask=mask, f=f), key)
+        return d, cols[None]
 
     sharded = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(spec, spec, spec),
-        out_specs=Diagrams(birth=spec, death=spec, dim=spec, valid=spec),
+        out_specs=(Diagrams(birth=spec, death=spec, dim=spec, valid=spec),
+                   spec),
         check_vma=False,
     )
 
-    def executor(g: GraphBatch) -> Diagrams:
+    def executor(g: GraphBatch) -> tuple[Diagrams, jax.Array]:
         return sharded(g.adj, g.mask, g.f)
 
     return executor

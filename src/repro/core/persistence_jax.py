@@ -6,6 +6,13 @@ reduction (pivot-chase with `low`) runs under `lax.fori_loop`/`while_loop`.
 Everything vmaps over a GraphBatch and pjit-shards over the data axis — this
 is the paper's workload (millions of small ego-net PDs) as one SPMD program.
 
+Each per-dimension column loop stops at the batch's last real simplex of
+that dimension, not at the block's cap: padded columns are all-zero and form
+a suffix in rank order, so they reduce to nothing.  The bound is the batch
+maximum, taken outside the per-graph ``vmap`` and passed in unmapped: a
+per-row bound would make the loop's predicate batched, and ``vmap`` would
+then select the whole block carry at every step.
+
 A Pallas kernel with the identical algorithm living entirely in VMEM is in
 repro/kernels/gf2_reduce.py; this module is its jnp reference and the default
 CPU path.
@@ -52,13 +59,22 @@ def _low(col: jax.Array) -> jax.Array:
     return jnp.where(any_bit, widx.astype(jnp.int32) * WORD + bit, -1)
 
 
-def reduce_packed(b: jax.Array, n_rows: int | None = None) -> tuple[jax.Array, jax.Array]:
+def reduce_packed(b: jax.Array, n_rows: int | None = None,
+                  n_cols: jax.Array | None = None
+                  ) -> tuple[jax.Array, jax.Array]:
     """Run the standard reduction. Returns (pivot_owner, positive).
 
     pivot_owner: (n_rows,) i32, pivot_owner[i] = j if column j kills row
     (simplex) i, else -1.  positive: (S,) bool, column reduced to zero (a
     birth).  In the flat square case rows == columns (n_rows = S); the
     per-dimension block path passes rectangular blocks.
+
+    The column loop runs over columns ``0 .. n_cols - 1`` (all S when
+    ``n_cols`` is None).  ``n_cols`` may be traced; the caller vouches that
+    every later column is zero, and those columns stay ``positive = False``
+    and own no row.  Under ``vmap`` pass it unmapped: a batched bound makes
+    the loop's predicate batched, and every step then selects the whole
+    carried block.
     """
     s = b.shape[0]
     n_rows = s if n_rows is None else n_rows
@@ -103,11 +119,12 @@ def reduce_packed(b: jax.Array, n_rows: int | None = None) -> tuple[jax.Array, j
 
     owner0 = jnp.full((n_rows,), -1, jnp.int32)
     pos0 = jnp.zeros((s,), bool)
-    _, owner, positive = lax.fori_loop(0, s, col_body, (b, owner0, pos0))
+    stop = s if n_cols is None else n_cols
+    _, owner, positive = lax.fori_loop(0, stop, col_body, (b, owner0, pos0))
     return owner, positive
 
 
-def _block_caps(fc: FilteredComplex, n: int, edge_cap: int, tri_cap: int,
+def _block_caps(n: int, edge_cap: int, tri_cap: int,
                 quad_cap: int) -> list[int]:
     caps = [n, edge_cap]
     if tri_cap:
@@ -162,13 +179,28 @@ def pack_boundary_blocks(fc: FilteredComplex, caps: list[int]):
 
 
 def reduce_packed_blocks(fc: FilteredComplex, caps: list[int],
-                         inner=reduce_packed):
-    """Per-dimension block reduction; returns global (owner, positive)."""
+                         inner=reduce_packed, n_cols: jax.Array | None = None):
+    """Per-dimension block reduction; returns global (owner, positive).
+
+    ``n_cols[d - 1]`` bounds block d's column loop (``reduce_packed``'s
+    ``n_cols``); it must be at least this graph's count of dim-d simplices,
+    whose ranks are the block's first columns.  Under ``vmap`` it is the
+    batch's maximum, passed unmapped (see ``reduce_packed``).  Columns past
+    the bound are padding: their ``positive`` is dropped below with every
+    padded column's, so the result does not depend on the bound.  None runs
+    every column, and is the only choice for an ``inner`` that takes no
+    bound (the Pallas kernel).
+    """
     blocks, ranks, pos_of_rank = pack_boundary_blocks(fc, caps)
     owner = jnp.full((fc.size,), -1, jnp.int32)
-    positive = sel0 = (fc.dims == 0)  # vertices: always births
+    positive = (fc.dims == 0)  # vertices: always births
     for d in range(1, len(caps)):
-        own_d, pos_d = inner(blocks[d - 1], caps[d - 1])  # rows: dim d-1 ranks
+        # rows: dim d-1 ranks
+        if n_cols is None:
+            own_d, pos_d = inner(blocks[d - 1], caps[d - 1])
+        else:
+            own_d, pos_d = inner(blocks[d - 1], caps[d - 1],
+                                 n_cols=n_cols[d - 1])
         # rows killed by a dim-d column
         killed = own_d >= 0
         row_pos = pos_of_rank[d - 1]
@@ -246,6 +278,65 @@ def pairs_to_diagrams(
     return Diagrams(birth=birth, death=death, dim=jnp.where(valid, fc.dims, -1), valid=valid)
 
 
+def persistence_diagrams_counted(
+    g: GraphBatch,
+    max_dim: int = 1,
+    edge_cap: int = 256,
+    tri_cap: int = 512,
+    quad_cap: int = 0,
+    sublevel: bool = True,
+    reducer: str = "jnp",
+) -> tuple[Diagrams, jax.Array]:
+    """Exact PDs of every graph in the batch, and the GF(2) loop's length.
+
+    Returns ``(diagrams, columns)``: ``columns`` is (2,) i32, the column
+    steps the batch's GF(2) loops ran and the column slots of its caps.
+    With ``reducer="jnp"`` block d's loop stops at the batch's largest
+    count of dim-d simplices; the other reducers run every slot.
+
+    reducer: "jnp" (this module) or "pallas" (VMEM kernel, interpret on
+    CPU) per-dimension blocks; "jnp-flat" / "pallas-flat" one (S, S/32)
+    matrix.
+    """
+    n = g.adj.shape[-1]
+    fc = jax.vmap(partial(
+        build_filtered_complex, max_dim=max_dim, edge_cap=edge_cap,
+        tri_cap=tri_cap, quad_cap=quad_cap, sublevel=sublevel,
+    ))(g.adj, g.mask, g.f)
+    if reducer in ("jnp", "pallas"):
+        # per-dimension block reduction (§Perf iteration 3, default)
+        caps = _block_caps(n, edge_cap, tri_cap, quad_cap)
+        slots = sum(caps[1:])
+        if reducer == "pallas":
+            from repro.kernels import ops as kops
+
+            owner, positive = jax.vmap(lambda c: reduce_packed_blocks(
+                c, caps, inner=kops.gf2_reduce))(fc)
+            run = slots
+        else:
+            # the dim-d simplices are ranks 0 .. count - 1 of block d, so
+            # the batch's largest count bounds its loop, unmapped
+            n_cols = jnp.stack([
+                jnp.max(jnp.sum(fc.dims == d, axis=-1), initial=0)
+                for d in range(1, len(caps))]).astype(jnp.int32)
+            owner, positive = jax.vmap(
+                lambda c, k: reduce_packed_blocks(c, caps, n_cols=k),
+                in_axes=(0, None))(fc, n_cols)
+            run = jnp.sum(n_cols)
+    else:  # "jnp-flat" / "pallas-flat": one (S, S/32) matrix
+        b = jax.vmap(pack_boundary)(fc)
+        if reducer == "pallas-flat":
+            from repro.kernels import ops as kops
+
+            owner, positive = jax.vmap(kops.gf2_reduce)(b)
+        else:
+            owner, positive = jax.vmap(reduce_packed)(b)
+        run = slots = fc.size
+    d = jax.vmap(partial(pairs_to_diagrams, max_dim=max_dim,
+                         sublevel=sublevel))(fc, owner, positive)
+    return d, jnp.stack([jnp.int32(run), jnp.int32(slots)])
+
+
 @partial(
     jax.jit,
     static_argnames=("max_dim", "edge_cap", "tri_cap", "quad_cap", "sublevel", "reducer"),
@@ -259,37 +350,10 @@ def persistence_diagrams_batched(
     sublevel: bool = True,
     reducer: str = "jnp",
 ) -> Diagrams:
-    """Exact PDs of every graph in the batch (vmapped bit-packed reduction).
-
-    reducer: "jnp" (this module) or "pallas" (VMEM kernel, interpret on CPU).
-    """
-
-    def one(adj, mask, f):
-        fc = build_filtered_complex(
-            adj, mask, f, max_dim, edge_cap, tri_cap, quad_cap, sublevel
-        )
-        n = adj.shape[-1]
-        if reducer in ("jnp", "pallas"):
-            # per-dimension block reduction (§Perf iteration 3, default)
-            if reducer == "pallas":
-                from repro.kernels import ops as kops
-
-                inner = kops.gf2_reduce
-            else:
-                inner = reduce_packed
-            caps = _block_caps(fc, n, edge_cap, tri_cap, quad_cap)
-            owner, positive = reduce_packed_blocks(fc, caps, inner=inner)
-        else:  # "jnp-flat" / "pallas-flat": one (S, S/32) matrix
-            b = pack_boundary(fc)
-            if reducer == "pallas-flat":
-                from repro.kernels import ops as kops
-
-                owner, positive = kops.gf2_reduce(b)
-            else:
-                owner, positive = reduce_packed(b)
-        return pairs_to_diagrams(fc, owner, positive, max_dim, sublevel)
-
-    return jax.vmap(one)(g.adj, g.mask, g.f)
+    """Exact PDs of every graph in the batch (vmapped bit-packed reduction;
+    ``persistence_diagrams_counted`` without the loop's length)."""
+    return persistence_diagrams_counted(
+        g, max_dim, edge_cap, tri_cap, quad_cap, sublevel, reducer)[0]
 
 
 def diagrams_bitwise_equal(a: Diagrams, b: Diagrams) -> bool:

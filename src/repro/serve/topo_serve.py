@@ -42,7 +42,7 @@ import numpy as np
 from repro import obs
 from repro.obs import flight as _flight
 from repro.obs.context import DeadlineExceeded, resolve_submit
-from repro.core.api import TopoPlan, make_topo_plan
+from repro.core.api import TopoPlan, collect_columns, make_topo_plan
 from repro.core.graph import GraphBatch, from_edge_lists
 from repro.core.persistence_jax import Diagrams
 from repro.core.reduction import passes_for_method
@@ -59,7 +59,8 @@ _C_SUBMITTED = obs.counter("serve.submitted",
 _C_SERVED = obs.counter("serve.served", help="futures resolved per bucket")
 _C_RESOLVE_BYTES = obs.counter(
     "serve.resolve_bytes",
-    help="device->host bytes of executed batches' diagrams per bucket")
+    help="device->host bytes of executed batches' diagrams (and their "
+         "GF(2) column steps) per bucket")
 _C_FAILED = obs.counter("serve.failed", help="futures failed at drain")
 _C_BATCHES = obs.counter("serve.batches", help="executed batches per bucket")
 _C_PADDED = obs.counter("serve.padded_rows",
@@ -79,6 +80,12 @@ _C_REDUCED_T = obs.counter(
 _C_PERSIST_CALLS = obs.counter(
     "serve.persist_calls",
     help="persist programs run by two-phase batches per bucket")
+_C_PERSIST_COLUMNS = obs.counter(
+    "serve.persist_columns",
+    help="GF(2) column-loop steps run by executed batches per bucket")
+_C_PERSIST_COLUMN_SLOTS = obs.counter(
+    "serve.persist_column_slots",
+    help="GF(2) column slots of the caps of executed batches per bucket")
 _H_QWAIT = obs.histogram(
     "serve.queue_wait_seconds",
     help="submit -> drain-pickup wait per request (picked_at - submitted_at)")
@@ -589,13 +596,14 @@ class TopoServe:
                 with obs.span("serve.gather", bucket=lbl, pad=n_pad_rows):
                     g = pack_requests(reqs, bucket, len(reqs) + n_pad_rows)
                 plan = self.plan_for(bucket)
-                if plan.key.repack == "on":
-                    # two-phase drain: reduce → measure → repack → persist;
-                    # the report carries each request's persist-rung
-                    # assignment (plan.* spans nest here)
-                    d, repack_info = plan.execute_info(g)
-                else:
-                    d = plan.execute(g)
+                with collect_columns() as columns:
+                    if plan.key.repack == "on":
+                        # two-phase drain: reduce → measure → repack →
+                        # persist; the report carries each request's
+                        # persist-rung assignment (plan.* spans nest here)
+                        d, repack_info = plan.execute_info(g)
+                    else:
+                        d = plan.execute(g)
                 with obs.span("serve.sync"):
                     jax.block_until_ready(d.birth)
             except Exception as e:  # resolve, don't wedge waiting clients
@@ -610,10 +618,12 @@ class TopoServe:
             served = n_failed = 0
             with obs.span("serve.resolve") as sp:
                 # one device->host transfer per leaf for the whole batch
-                # (pad rows included), then a host-owned copy of each row
-                # so a client holding one answer does not pin the batch
-                host = jax.device_get(d)
-                n_bytes = sum(x.nbytes for x in jax.tree.leaves(host))
+                # (pad rows and the column steps included), then a
+                # host-owned copy of each row so a client holding one
+                # answer does not pin the batch
+                host, columns = jax.device_get((d, columns))
+                n_bytes = sum(x.nbytes for x in jax.tree.leaves(
+                    (host, columns)))
                 sp.set(bytes=n_bytes)
                 for i, f in enumerate(futs):
                     err = None
@@ -632,6 +642,10 @@ class TopoServe:
         _C_RESOLVE_BYTES.inc(n_bytes, instance=inst, bucket=lbl)
         _C_SERVED.inc(served, instance=inst, bucket=lbl)
         _C_BATCHES.inc(instance=inst, bucket=lbl)
+        steps, slots = sum((c.reshape(-1, 2).sum(0) for c in columns),
+                           np.zeros(2, np.int64))
+        _C_PERSIST_COLUMNS.inc(int(steps), instance=inst, bucket=lbl)
+        _C_PERSIST_COLUMN_SLOTS.inc(int(slots), instance=inst, bucket=lbl)
         _flight.record("serve", "batch", frontend="topo", bucket=lbl,
                        graphs=len(futs))
         if n_failed:

@@ -219,9 +219,51 @@ def test_batch_resolves_with_one_transfer_and_no_per_graph_programs(
     assert [e["args"]["bytes"] for e in spans] == gets
     after = obs.get_instrument("serve.resolve_bytes").value(
         instance=srv._obs_instance, bucket="n16")
-    # the whole padded batch moved: 4 rows of each leaf, 3 handed out
+    # the whole padded batch moved: 4 rows of each leaf, 3 handed out,
+    # and the GF(2) loop's column steps, (2,) int32
     row_bytes = sum(x.nbytes for x in jax.tree.leaves(futs[0].result()))
-    assert after - before == gets[0] == 4 * row_bytes
+    assert after - before == gets[0] == 4 * row_bytes + 2 * 4
+
+
+@pytest.mark.parametrize("repack", ["off", "on"])
+def test_batch_counts_its_gf2_column_steps(monkeypatch, repack):
+    """The column loop stops at the batch's last real simplex: a batch of
+    an empty graph and a wheel runs the wheel's edges plus triangles, out
+    of the caps' slots (the bucket's, or two-phase each persist rung's),
+    fetched with the diagrams in one transfer."""
+    from repro import obs
+
+    bucket = Bucket(16, 64, 96)
+    srv = TopoServe(TopoServeConfig(method="none", buckets=(bucket,),
+                                    pad_batch_to=2, repack=repack))
+    wheel = nx.wheel_graph(7)          # hub + 6-cycle
+    k, t = wheel.number_of_edges(), 6
+    srv.submit(*_graph_query(wheel))
+    srv.drain()  # compile outside the count
+
+    def counted(name):
+        return obs.get_instrument(name).value(instance=srv._obs_instance,
+                                              bucket="n16")
+
+    gets = []
+    real_get = jax.device_get
+
+    def device_get(x):
+        gets.append(x)
+        return real_get(x)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    cols, slots = (counted("serve.persist_columns"),
+                   counted("serve.persist_column_slots"))
+    futs = [srv.submit([], 1), srv.submit(*_graph_query(wheel))]
+    assert srv.drain() == 2
+    assert len(gets) == 1
+    assert counted("serve.persist_columns") - cols == k + t == 18
+    rungs = ({bucket} if repack == "off"
+             else {f.repack_class for f in futs})   # one program a rung
+    assert (counted("serve.persist_column_slots") - slots
+            == sum(r.edge_cap + r.tri_cap for r in rungs))
+    assert int(futs[1].result().betti(1)) == 0
 
 
 def test_served_diagram_values():
